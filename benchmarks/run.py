@@ -23,8 +23,11 @@ import traceback
 from benchmarks.common import Rows
 
 
-def main() -> None:
+def main() -> int:
+    """Runs the suites; returns 1 when any of them raised (its row says
+    ERROR and the rest still run), else 0."""
     rows = Rows()
+    failed = 0
     only = sys.argv[1] if len(sys.argv) > 1 else None
     suites = [
         ("calibration", "benchmarks.bench_calibration"),
@@ -55,6 +58,7 @@ def main() -> None:
         except Exception as e:
             traceback.print_exc()
             rows.add(f"{name}/ERROR", 0.0, repr(e))
+            failed += 1
     if ft_out and (not only or only == "fault_tolerance"):
         try:
             from benchmarks.bench_fault_tolerance import cost_efficiency
@@ -62,9 +66,11 @@ def main() -> None:
         except Exception as e:
             traceback.print_exc()
             rows.add("cost_efficiency/ERROR", 0.0, repr(e))
+            failed += 1
     print("name,us_per_call,derived")
     rows.emit()
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -99,14 +99,13 @@ def calibrate_allreduce(sizes_bytes: Sequence[int] = (1 << 16, 1 << 20),
     times, sizes = [], []
     if len(devs) >= 2:
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         mesh = Mesh(np.array(devs), ("x",))
         for nbytes in sizes_bytes:
             n = max(1, nbytes // jnp.dtype(dtype).itemsize)
             x = jnp.ones((len(devs), n), dtype)
             f = jax.jit(
-                shard_map(lambda a: jax.lax.psum(a, "x"), mesh=mesh,
-                          in_specs=P("x", None), out_specs=P("x", None)))
+                jax.shard_map(lambda a: jax.lax.psum(a, "x"), mesh=mesh,
+                              in_specs=P("x", None), out_specs=P("x", None)))
             dt = _time_fn(f, x)
             times.append(dt)
             sizes.append(nbytes)

@@ -1,6 +1,5 @@
 # Pallas TPU kernels for the serving hot spots (DESIGN.md §8):
-#   flash_attention.py  — prefill attention (online softmax, causal/SWA, GQA)
-#   decode_attention.py — single-token GQA decode, contiguous or paged KV
-#   chunk_attention.py  — flash chunk-prefill vs a contiguous or paged prefix
-#   ssd_scan.py         — Mamba2 SSD chunked scan
+#   attention.py — one online-softmax GQA body behind prefill (flash),
+#                  chunked prefill and decode, over contiguous or paged KV
+#   ssd_scan.py  — Mamba2 SSD chunked scan
 # ops.py — jit'd dispatch (interpret=True on CPU); ref.py — pure-jnp oracles.

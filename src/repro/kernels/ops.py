@@ -1,8 +1,10 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
-On CPU (this container) kernels run in interpret mode — the kernel body
-executes in Python for correctness validation. On TPU they compile to
-Mosaic. Models call these through ``use_pallas=True``.
+On the CPU backend (tests) kernels run in interpret mode — the kernel body
+executes in Python for correctness validation. On any other backend they
+compile for the device, and a case the kernels do not cover raises
+instead of quietly running a jnp oracle in their place. Models call these
+through ``use_pallas=True``.
 """
 
 from __future__ import annotations
@@ -12,9 +14,7 @@ from typing import Optional, Tuple
 
 import jax
 
-from repro.kernels import chunk_attention as _ca
-from repro.kernels import flash_attention as _fa
-from repro.kernels import decode_attention as _da
+from repro.kernels import attention as _attn
 from repro.kernels import ssd_scan as _ssd
 
 
@@ -45,16 +45,21 @@ def _check_probe(out, probe: bool):
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None, block_q: int = 128,
                     block_kv: int = 128):
-    return _fa.flash_attention(q, k, v, causal=causal, window=window,
-                               block_q=block_q, block_kv=block_kv,
-                               interpret=_interpret())
+    return _attn.flash_attention(q, k, v, causal=causal, window=window,
+                                 block_q=block_q, block_kv=block_kv,
+                                 interpret=_interpret())
 
 
 def decode_attention(q, cache_k, cache_v, pos, slot_pos=None, *,
                      window: Optional[int] = None, block_kv: int = 128):
-    """Matches models.attention.decode_attention's signature; ring caches
-    (slot_pos) fall back to the jnp path — the kernel serves linear caches."""
+    """Matches models.attention.decode_attention's signature. The kernel
+    serves linear caches; ring caches (``slot_pos``) have no kernel and
+    run the jnp path on the CPU backend only."""
     if slot_pos is not None:
+        if not _interpret():
+            raise NotImplementedError(
+                "no Pallas kernel for ring (sliding-window slot) caches; "
+                "allocate a linear cache (ring=False)")
         from repro.models.attention import decode_attention as jref
         return jref(q, cache_k, cache_v, pos, slot_pos, window=window)
     return _decode_jit(q, cache_k, cache_v, pos, window=window,
@@ -63,9 +68,8 @@ def decode_attention(q, cache_k, cache_v, pos, slot_pos=None, *,
 
 @functools.partial(jax.jit, static_argnames=("window", "block_kv"))
 def _decode_jit(q, cache_k, cache_v, pos, *, window, block_kv):
-    return _da.decode_attention(q, cache_k, cache_v, pos, window=window,
-                                block_kv=min(block_kv, cache_k.shape[1]),
-                                interpret=_interpret())
+    return _attn.decode_attention(q, cache_k, cache_v, pos, window=window,
+                                  block_kv=block_kv, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("window", "probe"))
@@ -74,9 +78,9 @@ def decode_attention_paged(q, cache_k, cache_v, block_tbl, pos, *,
                            probe: bool = False):
     """Block-pool decode kernel; matches
     models.attention.decode_attention_paged's signature."""
-    out = _da.decode_attention_paged(q, cache_k, cache_v, block_tbl, pos,
-                                     window=window, probe=probe,
-                                     interpret=_interpret())
+    out = _attn.decode_attention_paged(q, cache_k, cache_v, block_tbl, pos,
+                                       window=window, probe=probe,
+                                       interpret=_interpret())
     return _check_probe(out, probe)
 
 
@@ -86,22 +90,10 @@ def chunk_attention(q, cache_k, cache_v, bases, *,
                     window: Optional[int] = None, block_q: int = 128,
                     block_kv: int = 128):
     """Flash chunk kernel against a linear cache. ``bases`` is scalar or
-    (B,): row b's C queries sit at absolute positions ``bases[b]+[0,C)``.
-    Non-tiling shapes fall back to the jnp oracle (shape checks are
-    trace-time static)."""
-    c, s = q.shape[1], cache_k.shape[1]
-    if c % min(block_q, c) or s % min(block_kv, s):
-        from repro.models import attention as _attn
-        import jax.numpy as jnp
-        bases = jnp.asarray(bases, jnp.int32)
-        q_pos = (jnp.broadcast_to(bases, (q.shape[0],))[:, None]
-                 + jnp.arange(c)[None] if bases.ndim == 0
-                 else bases[:, None] + jnp.arange(c)[None])
-        return _attn.chunk_attention(q, cache_k, cache_v, q_pos,
-                                     window=window)
-    return _ca.chunk_attention(q, cache_k, cache_v, bases, window=window,
-                               block_q=block_q, block_kv=block_kv,
-                               interpret=_interpret())
+    (B,): row b's C queries sit at absolute positions ``bases[b]+[0,C)``."""
+    return _attn.chunk_attention(q, cache_k, cache_v, bases, window=window,
+                                 block_q=block_q, block_kv=block_kv,
+                                 interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("window", "block_q", "probe"))
@@ -112,19 +104,9 @@ def chunk_attention_paged(q, cache_k, cache_v, block_tbl, bases, *,
     via scalar prefetch — no gathered page view is materialized. Covers
     the engine chunk path (scalar base) and the prefix-share suffix path
     (per-row bases)."""
-    c = q.shape[1]
-    if c % min(block_q, c):
-        from repro.models import attention as _attn
-        import jax.numpy as jnp
-        bases = jnp.asarray(bases, jnp.int32)
-        q_pos = (jnp.broadcast_to(bases, (q.shape[0],))[:, None]
-                 + jnp.arange(c)[None] if bases.ndim == 0
-                 else bases[:, None] + jnp.arange(c)[None])
-        return _attn.chunk_attention_paged(q, cache_k, cache_v, block_tbl,
-                                           q_pos, window=window, probe=probe)
-    out = _ca.chunk_attention_paged(q, cache_k, cache_v, block_tbl, bases,
-                                    window=window, block_q=block_q,
-                                    probe=probe, interpret=_interpret())
+    out = _attn.chunk_attention_paged(q, cache_k, cache_v, block_tbl, bases,
+                                      window=window, block_q=block_q,
+                                      probe=probe, interpret=_interpret())
     return _check_probe(out, probe)
 
 

@@ -161,16 +161,10 @@ def parse_costs(hlo_text: str) -> Tuple[Dict[str, _CompCost], Optional[str]]:
 
 
 def normalize_cost_analysis(ca) -> Dict[str, float]:
-    """Normalize ``compiled.cost_analysis()`` across JAX versions.
-
-    Older JAX returns one flat dict; newer versions return a *list* of
-    per-computation dicts (the entry computation first).  Either way the
-    result is the entry computation's numeric properties as a plain dict.
-    """
+    """The numeric properties of ``compiled.cost_analysis()`` (a flat
+    dict, or None where the backend has no cost model) as a plain dict."""
     if ca is None:
         return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     return {k: float(v) for k, v in ca.items()
             if isinstance(v, (int, float))}
 

@@ -23,6 +23,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core import populate_cluster
 from repro.hw import AWS_INSTANCES, effective, paper_cluster
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import GlobalServer, ServeRequest, TensorStore
 
@@ -39,8 +40,16 @@ def main() -> None:
     ap.add_argument("--use-pallas", action="store_true",
                     help="route decode/flash Pallas kernels (interpret "
                          "mode on CPU)")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    width = ap.add_mutually_exclusive_group()
+    width.add_argument("--reduced", dest="reduced", action="store_true",
+                       default=True,
+                       help="toy widths in float32 (default; runs on CPU)")
+    width.add_argument("--full", dest="reduced", action="store_false",
+                       help="the config's published widths")
+    ap.add_argument("--max-len", type=int, default=96,
+                    help="engine KV capacity per request (tokens)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     exec_cfg = cfg.reduced() if args.reduced else cfg
@@ -55,11 +64,11 @@ def main() -> None:
     for p in plan.pipelines:
         print("   ", p.describe())
 
-    # data plane: real engines on reduced config (CPU container)
+    # data plane: real engines (reduced widths unless --full)
     model = build_model(exec_cfg, remat=False, attn_chunk=0)
     params = model.init(jax.random.PRNGKey(0))
     store = TensorStore()
-    srv = GlobalServer(exec_cfg, store, max_batch=4, max_len=96,
+    srv = GlobalServer(exec_cfg, store, max_batch=4, max_len=args.max_len,
                        use_pallas=args.use_pallas,
                        prefill_chunk=args.prefill_chunk)
     for i, placement in enumerate(plan.pipelines[:2] or [None]):

@@ -193,7 +193,7 @@ def chunk_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
 # maps slot-virtual position t to pool block ``block_tbl[b, t // block]`` at
 # offset ``t % block``; unallocated entries point at the reserved trash block
 # 0, whose contents position masking keeps invisible. These are the pure-jnp
-# oracles for the Pallas gather kernel in ``repro.kernels.decode_attention``.
+# oracles for the Pallas paged kernels in ``repro.kernels.attention``.
 # ---------------------------------------------------------------------------
 def _gather_pages(cache_k: jax.Array, cache_v: jax.Array,
                   block_tbl: jax.Array) -> Tuple[jax.Array, jax.Array]:

@@ -172,14 +172,20 @@ class Engine:
                  admit_window: int = 4, prefix_share: bool = False,
                  grow_ahead: int = 1, admit_headroom: bool = True,
                  kv_sanitize: Optional[bool] = None,
-                 victim_policy: str = "cost", placement: Any = None):
+                 victim_policy: str = "cost", placement: Any = None,
+                 device: Optional[jax.Device] = None):
         assert admission in ("bucketed", "legacy"), admission
         assert kv_layout in ("auto", "paged", "contig"), kv_layout
         assert kv_alloc in ("lazy", "upfront"), kv_alloc
         assert victim_policy in ("cost", "fewest"), victim_policy
         _silence_cpu_donation_warnings()
         self.cfg = cfg
-        self.params = params
+        # params, KV cache and every host-built dispatch input live on
+        # ``device`` (None: the default device), so the jitted dispatches
+        # run there — one engine per chip behind the global server
+        self.device = device
+        self.params = (params if device is None
+                       else jax.device_put(params, device))
         self.max_batch = max_batch
         self.max_len = max_len
         self.admission = admission
@@ -245,10 +251,9 @@ class Engine:
             model_kw.setdefault("kv_probe", self.bm.sanitize)
         self._kv_probe = bool(model_kw.get("kv_probe", False))
         self.model = build_model(cfg, **model_kw)
+        with jax.default_device(device):
+            self.cache = self._put(self._init_cache(n_blocks, block_size))
         if kv_layout == "paged":
-            self.cache = self.model.init_cache(
-                max_batch, max_len, vector_pos=True, kv_layout="paged",
-                n_blocks=n_blocks, block_size=block_size)
             if prefix_share:
                 if admission == "legacy":
                     raise ValueError(
@@ -256,13 +261,6 @@ class Engine:
                 from repro.serving.prefix_index import PrefixIndex
                 self._prefix = PrefixIndex(block_size, self.bm)
                 self.bm.on_reuse = self._prefix.invalidate_block
-        elif cfg.is_encdec:
-            self.cache = self.model.init_cache(max_batch, max_len,
-                                               s_enc=self.enc_frames,
-                                               vector_pos=True)
-        else:
-            self.cache = self.model.init_cache(max_batch, max_len,
-                                               ring=False, vector_pos=True)
         self.slots: List[Optional[ServeRequest]] = [None] * max_batch
         self.stats = EngineStats()
         self._pending: List[_PendingGroup] = []
@@ -430,6 +428,23 @@ class Engine:
         self._cow = jax.jit(cow_fn, donate_argnums=(0,))
         self._warm = jax.jit(warm_fn, donate_argnums=(0,))
 
+    def _init_cache(self, n_blocks: int, block_size: int) -> Dict:
+        if self.kv_layout == "paged":
+            return self.model.init_cache(
+                self.max_batch, self.max_len, vector_pos=True,
+                kv_layout="paged", n_blocks=n_blocks, block_size=block_size)
+        if self.cfg.is_encdec:
+            return self.model.init_cache(self.max_batch, self.max_len,
+                                         s_enc=self.enc_frames,
+                                         vector_pos=True)
+        return self.model.init_cache(self.max_batch, self.max_len,
+                                     ring=False, vector_pos=True)
+
+    def _put(self, x):
+        """Host value (or another device's array) -> array on this
+        engine's device."""
+        return jax.device_put(x, self.device)
+
     def _run(self, fn, *args):
         """Dispatch a (possibly checkify'd) jit: with the poison probe
         armed the device-side checks are discharged here — sanitize/debug
@@ -511,7 +526,7 @@ class Engine:
         if self.bm is None or not self.bm.sanitize \
                 or not self.bm.last_released:
             return
-        ids = jnp.asarray(self.bm.last_released)
+        ids = self._put(np.asarray(self.bm.last_released, np.int32))
         self.cache["k"] = self.cache["k"].at[:, ids].set(KV_POISON)
         self.cache["v"] = self.cache["v"].at[:, ids].set(KV_POISON)
         self.bm.last_released = []
@@ -520,7 +535,7 @@ class Engine:
         """Push the host-side block table to the device cache when
         allocations changed since the last dispatch."""
         if self.bm is not None and self._tbl_dirty:
-            self.cache["block_tbl"] = jnp.asarray(self.bm.table)
+            self.cache["block_tbl"] = self._put(self.bm.table)
             self._tbl_dirty = False
 
     def block_stats(self) -> Dict[str, int]:
@@ -635,8 +650,9 @@ class Engine:
                         cow = (match.boundary, dst)
                     else:
                         self.bm.note_cow(match.boundary, dst)
-                        self.cache = self._cow(self.cache, jnp.asarray(
-                            match.boundary), jnp.asarray(dst))
+                        self.cache = self._cow(self.cache, self._put(
+                            np.int32(match.boundary)), self._put(
+                            np.int32(dst)))
                         self.stats.cow_copies += 1
                 self.stats.prefix_hits += 1
                 self.stats.prefix_shared_tokens += match.n_tokens
@@ -688,7 +704,7 @@ class Engine:
         lens[n:] = lens[0]
         slots[n:] = slots[0]
         logits, group_cache = self._prefill_b(
-            self.params, jnp.asarray(tokens), jnp.asarray(lens - 1))
+            self.params, self._put(tokens), self._put(lens - 1))
         self._scatter_group(group_cache, slots, rows, lens)
         # jaxlint: disable=host-sync -- intended: sampled first tokens
         # must land on the host to fill req.generated
@@ -711,8 +727,8 @@ class Engine:
         for j, (r, toks, slot, n_sh, cow) in enumerate(items):
             if cow is not None:       # deferred COW: donor prefilled by now
                 self.bm.note_cow(cow[0], cow[1])
-                self.cache = self._cow(self.cache, jnp.asarray(cow[0]),
-                                       jnp.asarray(cow[1]))
+                self.cache = self._cow(self.cache, self._put(np.int32(cow[0])),
+                                       self._put(np.int32(cow[1])))
                 self.stats.cow_copies += 1
             suf = toks[n_sh:]
             tokens[j, :len(suf)] = suf
@@ -727,9 +743,9 @@ class Engine:
         slots[n:] = slots[0]
         tbls = self.bm.table[slots]
         logits, self.cache = self._run(
-            self._suffix, self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(bases), jnp.asarray(lens), jnp.asarray(slots),
-            jnp.asarray(tbls))
+            self._suffix, self.params, self.cache, self._put(tokens),
+            self._put(bases), self._put(lens), self._put(slots),
+            self._put(tbls))
         # jaxlint: disable=host-sync -- intended: sampled first tokens
         # must land on the host to fill req.generated
         first = np.asarray(self.model.sample_greedy(logits))
@@ -749,9 +765,9 @@ class Engine:
     def _scatter_group(self, group_cache, slots, rows, lens) -> None:
         """Fused install of a (remapped) group cache into slot rows, routed
         through the block tables under the paged layout."""
-        args = [jnp.asarray(slots), jnp.asarray(rows), jnp.asarray(lens)]
+        args = [self._put(slots), self._put(rows), self._put(lens)]
         if self.bm is not None:
-            args.append(jnp.asarray(self.bm.table[slots]))
+            args.append(self._put(self.bm.table[slots]))
         self.cache = self._scatter(self.cache, group_cache, *args)
 
     def _install(self, req: ServeRequest, slot: int, first_tok) -> None:
@@ -819,7 +835,9 @@ class Engine:
             frames = jnp.zeros((g, self.enc_frames, self.cfg.d_model),
                                jnp.float32)
             return self._enc_warm(self.params, frames)
-        return self.model.init_cache(g, self.max_len, ring=False)
+        with jax.default_device(self.device):
+            return self._put(self.model.init_cache(g, self.max_len,
+                                                   ring=False))
 
     def _advance_pending(self) -> None:
         """One chunk of prefill work per pending GROUP, interleaved between
@@ -862,16 +880,15 @@ class Engine:
                             self.bm.check_write(m.slot, grp.base, hi)
                 logits, self.cache = self._run(
                     self._chunk_paged, self.params, self.cache,
-                    jnp.asarray(chunk), jnp.asarray(grp.base, jnp.int32),
-                    jnp.asarray(last_idx), jnp.asarray(rem),
-                    jnp.asarray(tbls))
+                    self._put(chunk), self._put(np.int32(grp.base)),
+                    self._put(last_idx), self._put(rem), self._put(tbls))
                 self.stats.chunk_direct += 1
             else:
                 if grp.cache is None:
                     grp.cache = self._chunk_init(g)
                 logits, grp.cache = self._chunk(
-                    self.params, grp.cache, jnp.asarray(chunk),
-                    jnp.asarray(grp.base, jnp.int32), jnp.asarray(last_idx))
+                    self.params, grp.cache, self._put(chunk),
+                    self._put(np.int32(grp.base)), self._put(last_idx))
             self.stats.prefill_chunks += 1
             grp.base += c
             finishers = [(j, m) for j, m in enumerate(grp.members)
@@ -895,7 +912,7 @@ class Engine:
         lens = np.array([len(m.tokens) for _, m in finishers], np.int32)
         if self.bm is not None:
             self.cache["pos"] = self.cache["pos"].at[
-                jnp.asarray(slots)].set(jnp.asarray(lens))
+                self._put(slots)].set(self._put(lens))
         else:
             rows = np.array([j for j, _ in finishers], np.int32)
             self._scatter_group(grp.cache, slots, rows, lens)
@@ -1053,8 +1070,8 @@ class Engine:
                                     self.slots[i].ctx_len)
         self._sync_block_tbl()
         logits, self.cache = self._run(self._decode, self.params,
-                                       self.cache, jnp.asarray(tokens),
-                                       jnp.asarray(mask))
+                                       self.cache, self._put(tokens),
+                                       self._put(mask))
         # jaxlint: disable=host-sync -- intended: THE per-step sync point.
         # Sampled tokens feed the next step's host-side scheduling; every
         # other sync in step() has been eliminated, so the pipeline stalls
@@ -1130,7 +1147,7 @@ class Engine:
             pos = self.slots[slot].ctx_len - 1
         self.bm.check_read(slot, pos)      # no-op unless sanitize mode
         nb = -(-pos // self.bm.block_size) if pos > 0 else 0
-        ids = jnp.asarray(self.bm.table[slot, :nb].copy())
+        ids = self._put(self.bm.table[slot, :nb].copy())
         self.stats.kv_exports += 1
         return {"k": self.cache["k"][:, ids], "v": self.cache["v"][:, ids],
                 "pos": int(pos), "block_size": self.bm.block_size,
@@ -1176,11 +1193,12 @@ class Engine:
         self.bm.note_live(slot, payload["pos"])
         self._tbl_dirty = True
         nb = payload["k"].shape[1]
-        ids = jnp.asarray(self.bm.table[slot, :nb].copy())
+        ids = self._put(self.bm.table[slot, :nb].copy())
+        # the payload may come from another pipeline's device
         self.cache["k"] = self.cache["k"].at[:, ids].set(
-            payload["k"].astype(self.cache["k"].dtype))
+            self._put(payload["k"]).astype(self.cache["k"].dtype))
         self.cache["v"] = self.cache["v"].at[:, ids].set(
-            payload["v"].astype(self.cache["v"].dtype))
+            self._put(payload["v"]).astype(self.cache["v"].dtype))
         self.cache["pos"] = self.cache["pos"].at[slot].set(payload["pos"])
         self.slots[slot] = req
         self.stats.kv_imports += 1
@@ -1197,7 +1215,7 @@ class Engine:
         ids = self._prefix.full_run(tokens)
         if not ids:
             return None
-        idsj = jnp.asarray(ids)
+        idsj = self._put(np.asarray(ids, np.int32))
         toks = [int(t) for t in tokens[:len(ids) * self.bm.block_size]]
         return {"k": self.cache["k"][:, idsj], "v": self.cache["v"][:, idsj],
                 "tokens": toks, "block_size": self.bm.block_size,
@@ -1240,9 +1258,9 @@ class Engine:
         ids = self.bm.warm_blocks(nb)
         if ids is None:
             return False             # pool too tight right now
-        idsj = jnp.asarray(ids)
-        self.cache = self._warm(self.cache, jnp.asarray(payload["k"][:, :nb]),
-                                jnp.asarray(payload["v"][:, :nb]), idsj)
+        idsj = self._put(np.asarray(ids, np.int32))
+        self.cache = self._warm(self.cache, self._put(payload["k"][:, :nb]),
+                                self._put(payload["v"][:, :nb]), idsj)
         self._prefix.insert(toks[:nb * self.bm.block_size], ids)
         self.bm.warm_release(ids)
         self.stats.prefix_warmups += 1

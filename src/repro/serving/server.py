@@ -191,11 +191,15 @@ class GlobalServer:
                      weight: Optional[float] = None, partition: str = "full",
                      placement=None,
                      engine_kw: Optional[Dict] = None,
-                     pricing: str = "spot") -> ServingPipeline:
+                     pricing: str = "spot",
+                     device: Optional[Any] = None) -> ServingPipeline:
         """pricing: which rate this pipeline is billed at — a cluster
         mixing spot and on-demand capacity prices the SAME placement
         differently, so cost-policy dispatch must re-rank per pipeline
-        (``BucketTable.weight(spot=...)``), not per spec."""
+        (``BucketTable.weight(spot=...)``), not per spec. device: the
+        ``jax.Device`` that holds this pipeline's params and KV cache and
+        runs its dispatches (one replica per chip); the engine rebuilt
+        after an interruption stays on it."""
         assert pricing in ("spot", "ondemand"), pricing
         if self.store is not None:
             key = f"{partition}/p{len(self.pipelines)}"
@@ -214,6 +218,8 @@ class GlobalServer:
                 bucket_tbl = self._bucket_table(placement)
         pid = len(self.pipelines)
         self._pipe_engine_kw[pid] = dict(engine_kw or {})
+        if device is not None:
+            self._pipe_engine_kw[pid]["device"] = device
         # the engine's cost-aware preemption-victim policy prices the
         # recompute branch off the pipeline's placement when known
         if placement is not None:

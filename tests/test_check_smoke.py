@@ -139,3 +139,16 @@ def test_routing_floor_bites():
         "routing/placement_mix,0.0,short_picks_low=0 mixed_picks_high=1\n")
     assert any("mix" in f for f in check_routing(wrong_mix))
     assert check_routing([]) == ["no routing/cost row found"]
+
+
+def test_bench_run_exits_nonzero_when_a_suite_errors(monkeypatch, capsys):
+    import types
+    from benchmarks import run
+
+    def boom(rows):
+        raise RuntimeError("suite failed")
+    monkeypatch.setitem(sys.modules, "benchmarks.bench_calibration",
+                        types.SimpleNamespace(run=boom))
+    monkeypatch.setattr(sys, "argv", ["run", "calibration"])
+    assert run.main() == 1
+    assert "calibration/ERROR" in capsys.readouterr().out

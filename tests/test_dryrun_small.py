@@ -21,10 +21,11 @@ SCRIPT = textwrap.dedent("""
     from repro.launch.steps import build_step, lower_step
     from repro.launch import hlo_utils
     from repro.launch.hlo_costs import normalize_cost_analysis
+    from repro.launch.mesh import make_mesh
 
     out = {}
     cfg = get_config("internlm2-1.8b").reduced()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     for shape in [ShapeSpec("t", 64, 8, "train_step"),
                   ShapeSpec("p", 64, 4, "prefill_step"),
                   ShapeSpec("d", 64, 8, "serve_step")]:
@@ -35,7 +36,7 @@ SCRIPT = textwrap.dedent("""
         out[shape.step] = {"flops": ca.get("flops", -1.0),
                            "coll": cb["total"]}
     # multi-pod mesh: DP serve + PP serve
-    mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh3 = make_mesh((2, 2, 2), ("pod", "data", "model"))
     for pp in (False, True):
         built = build_step(cfg, ShapeSpec("d", 64, 8, "serve_step"), mesh3,
                            serve_pp=pp)

@@ -67,7 +67,6 @@ def test_xla_cost_analysis_counts_scan_body_once():
     a = jax.ShapeDtypeStruct((64, 64), jnp.float32)
     ws = jax.ShapeDtypeStruct((8, 64, 64), jnp.float32)
     comp = jax.jit(scanned).lower(a, ws).compile()
-    # cost_analysis() is a dict on older JAX, a list of per-computation
-    # dicts on newer JAX — normalize before poking at it
+    # keep cost_analysis()'s numeric properties only
     ca = normalize_cost_analysis(comp.cost_analysis())
     assert ca["flops"] == pytest.approx(2 * 64 ** 3, rel=0.02)

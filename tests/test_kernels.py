@@ -7,11 +7,9 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref as kref
-from repro.kernels.chunk_attention import (chunk_attention,
-                                           chunk_attention_paged)
-from repro.kernels.decode_attention import (decode_attention,
-                                            decode_attention_paged)
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.attention import (chunk_attention, chunk_attention_paged,
+                                     decode_attention, decode_attention_paged,
+                                     flash_attention)
 from repro.kernels.ssd_scan import ssd_scan
 from repro.models import attention as mattn
 
@@ -74,6 +72,7 @@ def test_decode_attention_sweep(b, s, nh, nkv, d, window, vecpos, dtype):
         (1, 128, 256, 6, 6, 64, 32, False),      # SWA
         (2, 256, 512, 8, 2, 128, None, True),    # GQA, d=128, 2 q-tiles
         (1, 64, 128, 2, 1, 32, None, False),     # sub-tile chunk
+        (2, 96, 160, 4, 2, 64, None, True),      # lengths no block divides
     ])
 def test_chunk_attention_sweep(b, c, s, nh, nkv, d, window, vecbase, dtype):
     """Flash chunk kernel (linear cache) == jnp chunk oracle across
@@ -220,3 +219,15 @@ def test_model_pallas_path_matches_jnp_path():
     lp, cp = mp.prefill(params, {"tokens": toks}, max_len=24)
     np.testing.assert_allclose(np.asarray(lj), np.asarray(lp), atol=2e-3,
                                rtol=1e-2)
+
+
+def test_ring_cache_raises_off_the_cpu(monkeypatch):
+    """Ring caches have no kernel: off the CPU backend the dispatch
+    raises rather than running the jnp oracle in the kernel's place."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    q = jnp.zeros((1, 1, 2, 32), jnp.float32)
+    ck = jnp.zeros((1, 16, 1, 32), jnp.float32)
+    with pytest.raises(NotImplementedError, match="ring"):
+        ops.decode_attention(q, ck, ck, jnp.int32(3),
+                             jnp.zeros((1, 16), jnp.int32), window=8)

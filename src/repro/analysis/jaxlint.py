@@ -102,6 +102,7 @@ class LintConfig:
         r"^Engine\._finish_pending$",
         r"^Engine\._admit_group$",
         r"^Engine\._admit_group_suffix$",
+        r"^Engine\._sample$",
         r"^Engine\._scatter_group$",
         r"^Engine\._preempt$",
         r"^Engine\.export_kv$",

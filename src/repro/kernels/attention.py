@@ -151,12 +151,14 @@ def _attn_kernel(*refs, n_prefetch: int, scale: float, causal: bool,
 
 
 def _attend(q: jax.Array, k: jax.Array, v: jax.Array, bases,
-            block_tbl: Optional[jax.Array], *, causal: bool,
+            block_tbl: Optional[jax.Array], *, name: str, causal: bool,
             window: Optional[int], block_q: int, block_kv: int, probe: bool,
             interpret: bool):
     """q: (B,C,nh,d); k/v: contiguous (B,S,nkv,d) when ``block_tbl`` is
     None, else a pool (n_blocks, block, nkv, d) walked through
-    ``block_tbl`` (B, max_blocks). Returns o, or (o, probe_max)."""
+    ``block_tbl`` (B, max_blocks). Returns o, or (o, probe_max).
+    ``name`` names the kernel's custom call: the profiler trace and the
+    benchmark's roofline readers find the kernel by it."""
     b, c, nh, d = q.shape
     nkv = k.shape[2]
     assert nh % nkv == 0, (nh, nkv)
@@ -238,6 +240,7 @@ def _attend(q: jax.Array, k: jax.Array, v: jax.Array, bases,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name=name,
     )(*prefetch, q, k, v)
     o = jnp.swapaxes(out[0], 1, 2)
     return (o, out[1]) if probe else o
@@ -249,9 +252,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     interpret: bool = False) -> jax.Array:
     """Prefill self-attention. q: (B,Sq,nh,d), k/v: (B,Sk,nkv,d) ->
     (B,Sq,nh,d); causal queries and keys both start at position 0."""
-    return _attend(q, k, v, 0, None, causal=causal, window=window,
-                   block_q=block_q, block_kv=block_kv, probe=False,
-                   interpret=interpret)
+    return _attend(q, k, v, 0, None, name="flash_attention", causal=causal,
+                   window=window, block_q=block_q, block_kv=block_kv,
+                   probe=False, interpret=interpret)
 
 
 def chunk_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
@@ -262,9 +265,9 @@ def chunk_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
     written; bases scalar or (B,) — row b's queries sit at absolute
     positions ``bases[b] + [0, C)``. Returns o, or (o, probe_max) when
     ``probe`` is armed."""
-    return _attend(q, cache_k, cache_v, bases, None, causal=True,
-                   window=window, block_q=block_q, block_kv=block_kv,
-                   probe=probe, interpret=interpret)
+    return _attend(q, cache_k, cache_v, bases, None, name="chunk_attention",
+                   causal=True, window=window, block_q=block_q,
+                   block_kv=block_kv, probe=probe, interpret=interpret)
 
 
 def chunk_attention_paged(q: jax.Array, cache_k: jax.Array,
@@ -276,7 +279,8 @@ def chunk_attention_paged(q: jax.Array, cache_k: jax.Array,
     chunk already written; block_tbl: (B, max_blocks) int32 pool-block id
     per virtual block (0 = trash block, masked); bases scalar or (B,).
     Returns o, or (o, probe_max) when ``probe`` is armed."""
-    return _attend(q, cache_k, cache_v, bases, block_tbl, causal=True,
+    return _attend(q, cache_k, cache_v, bases, block_tbl,
+                   name="chunk_attention_paged", causal=True,
                    window=window, block_q=block_q, block_kv=0, probe=probe,
                    interpret=interpret)
 
@@ -287,9 +291,9 @@ def decode_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
                      ) -> jax.Array:
     """q: (B,1,nh,d); cache_k/v: (B,S,nkv,d); pos scalar or (B,) — the
     position of the current (already written) token per sequence."""
-    return _attend(q, cache_k, cache_v, pos, None, causal=True,
-                   window=window, block_q=1, block_kv=block_kv, probe=False,
-                   interpret=interpret)
+    return _attend(q, cache_k, cache_v, pos, None, name="decode_attention",
+                   causal=True, window=window, block_q=1, block_kv=block_kv,
+                   probe=False, interpret=interpret)
 
 
 def decode_attention_paged(q: jax.Array, cache_k: jax.Array,
@@ -300,6 +304,7 @@ def decode_attention_paged(q: jax.Array, cache_k: jax.Array,
     block_tbl: (B, max_blocks); pos scalar or (B,) — the position of the
     current (already written) token per sequence. Returns o, or
     (o, probe_max) when ``probe`` is armed."""
-    return _attend(q, cache_k, cache_v, pos, block_tbl, causal=True,
+    return _attend(q, cache_k, cache_v, pos, block_tbl,
+                   name="decode_attention_paged", causal=True,
                    window=window, block_q=1, block_kv=0, probe=probe,
                    interpret=interpret)

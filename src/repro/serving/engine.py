@@ -100,6 +100,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.models import build_model
+from repro.serving import obs
 from repro.serving.kv_blocks import KV_POISON, BlockManager
 from repro.serving.request import ServeRequest
 
@@ -133,7 +134,6 @@ class EngineStats:
     alloc_failures: int = 0     # paged admissions refused (backpressure)
     block_grows: int = 0        # blocks allocated on demand mid-decode
     preemptions: int = 0        # slots evicted when a grow found a dry pool
-    kv_exports: int = 0         # KV block sets published for migration
     kv_imports: int = 0         # re-admissions that attached KV (no prefill)
     prefix_hits: int = 0        # admissions that mapped shared-prefix blocks
     prefix_shared_tokens: int = 0   # prefill tokens NOT recomputed
@@ -577,6 +577,30 @@ class Engine:
         Requests are grouped by length bucket and prefilled in batches of
         ``prefill_group``; long contexts go to the chunked path (grouped
         into one dispatch per step). Finished ones surface via ``step()``."""
+        with obs.span("serve.admit") as sp:
+            with obs.span("serve.admit.plan"):
+                admitted, groups, sgroups, chunked = self._plan_admission(
+                    reqs)
+            for blen, items in sorted(groups.items()):
+                for i in range(0, len(items), self._group):
+                    self._admit_group(items[i:i + self._group], blen)
+            for blen, items in sorted(sgroups.items()):
+                for i in range(0, len(items), self._group):
+                    self._admit_group_suffix(items[i:i + self._group], blen)
+            # pendings admitted together share a group: one chunk dispatch
+            # per step for the whole group instead of a batch-1 loop
+            for i in range(0, len(chunked), self._group):
+                members = [_PendingMember(r, slot, np.asarray(toks, np.int32))
+                           for r, toks, slot in chunked[i:i + self._group]]
+                self._pending.append(_PendingGroup(members))
+            sp.set_metadata(n=len(admitted))
+        return admitted
+
+    def _plan_admission(self, reqs: Sequence[ServeRequest]):
+        """Host half of ``admit_many``: prefix match, block reservation and
+        grouping. Returns (admitted, bucketed groups, suffix groups,
+        chunked); only a boundary copy-on-write (or a legacy batch-1
+        prefill) dispatches here."""
         free = self.free_slots()
         admitted: List[ServeRequest] = []
         skipped = 0
@@ -633,6 +657,8 @@ class Engine:
                 self.bm.note_live(slot, ctx)         # true-frag accounting
                 self._tbl_dirty = True
             free.pop(0)
+            if not r.admit_ns:
+                r.admit_ns = obs.now_ns()
             if toks is None:
                 toks = self._prefill_tokens(r)
             if self.admission == "legacy":
@@ -649,11 +675,7 @@ class Engine:
                     if match.boundary in fresh_this_call:
                         cow = (match.boundary, dst)
                     else:
-                        self.bm.note_cow(match.boundary, dst)
-                        self.cache = self._cow(self.cache, self._put(
-                            np.int32(match.boundary)), self._put(
-                            np.int32(dst)))
-                        self.stats.cow_copies += 1
+                        self._copy_block(match.boundary, dst)
                 self.stats.prefix_hits += 1
                 self.stats.prefix_shared_tokens += match.n_tokens
                 sgroups.setdefault(
@@ -672,19 +694,7 @@ class Engine:
                     self._index_insert(toks, slot)
                     fresh_this_call.update(self.bm.slot_blocks(slot))
             admitted.append(r)
-        for blen, items in sorted(groups.items()):
-            for i in range(0, len(items), self._group):
-                self._admit_group(items[i:i + self._group], blen)
-        for blen, items in sorted(sgroups.items()):
-            for i in range(0, len(items), self._group):
-                self._admit_group_suffix(items[i:i + self._group], blen)
-        # pendings admitted together share a group: one chunk dispatch per
-        # step for the whole group instead of a batch-1 loop
-        for i in range(0, len(chunked), self._group):
-            members = [_PendingMember(r, slot, np.asarray(toks, np.int32))
-                       for r, toks, slot in chunked[i:i + self._group]]
-            self._pending.append(_PendingGroup(members))
-        return admitted
+        return admitted, groups, sgroups, chunked
 
     def _admit_group(self, items, blen: int) -> None:
         """One batched prefill + fused scatter for <= prefill_group
@@ -703,15 +713,15 @@ class Engine:
         # data, keeping the scatter deterministic
         lens[n:] = lens[0]
         slots[n:] = slots[0]
-        logits, group_cache = self._prefill_b(
-            self.params, self._put(tokens), self._put(lens - 1))
-        self._scatter_group(group_cache, slots, rows, lens)
-        # jaxlint: disable=host-sync -- intended: sampled first tokens
-        # must land on the host to fill req.generated
-        first = np.asarray(self.model.sample_greedy(logits))
+        with obs.span("serve.prefill", rows=n,
+                      tokens=sum(len(toks) for _, toks, _ in items)):
+            logits, group_cache = self._prefill_b(
+                self.params, self._put(tokens), self._put(lens - 1))
+            self._scatter_group(group_cache, slots, rows, lens)
+        first, t_ns = self._sample(logits, "first")
         self.stats.prefill_batches += 1
         for j, (r, toks, slot) in enumerate(items):
-            self._install(r, slot, first[j])
+            self._install(r, slot, first[j], t_ns)
 
     def _admit_group_suffix(self, items, blen: int) -> None:
         """Prefix-sharing admission: one batched SUFFIX prefill for <=
@@ -726,10 +736,7 @@ class Engine:
         slots = np.zeros((g,), np.int32)
         for j, (r, toks, slot, n_sh, cow) in enumerate(items):
             if cow is not None:       # deferred COW: donor prefilled by now
-                self.bm.note_cow(cow[0], cow[1])
-                self.cache = self._cow(self.cache, self._put(np.int32(cow[0])),
-                                       self._put(np.int32(cow[1])))
-                self.stats.cow_copies += 1
+                self._copy_block(*cow)
             suf = toks[n_sh:]
             tokens[j, :len(suf)] = suf
             bases[j] = n_sh
@@ -742,17 +749,36 @@ class Engine:
         lens[n:] = lens[0]
         slots[n:] = slots[0]
         tbls = self.bm.table[slots]
-        logits, self.cache = self._run(
-            self._suffix, self.params, self.cache, self._put(tokens),
-            self._put(bases), self._put(lens), self._put(slots),
-            self._put(tbls))
-        # jaxlint: disable=host-sync -- intended: sampled first tokens
-        # must land on the host to fill req.generated
-        first = np.asarray(self.model.sample_greedy(logits))
+        with obs.span("serve.suffix", rows=n,
+                      tokens=sum(len(t) - k for _, t, _, k, _ in items)):
+            logits, self.cache = self._run(
+                self._suffix, self.params, self.cache, self._put(tokens),
+                self._put(bases), self._put(lens), self._put(slots),
+                self._put(tbls))
+        first, t_ns = self._sample(logits, "first")
         self.stats.prefill_batches += 1
         for j, (r, toks, slot, n_sh, cow) in enumerate(items):
             self._index_insert(toks, slot)
-            self._install(r, slot, first[j])
+            self._install(r, slot, first[j], t_ns)
+
+    def _copy_block(self, src: int, dst: int) -> None:
+        """Copy-on-write of a partially shared boundary block, before any
+        suffix write lands in it."""
+        with obs.span("serve.cow", rows=1, tokens=self.bm.block_size):
+            self.bm.note_cow(src, dst)
+            self.cache = self._cow(self.cache, self._put(np.int32(src)),
+                                   self._put(np.int32(dst)))
+        self.stats.cow_copies += 1
+
+    def _sample(self, logits, what: str) -> Tuple[np.ndarray, int]:
+        """Wait for the greedy tokens of ``logits`` on the host; returns
+        them with the host time they landed. ``what`` is "first" (an
+        admission's first tokens) or "decode"."""
+        with obs.span("serve.sync", what=what):
+            # jaxlint: disable=host-sync -- intended: the engine's waits for
+            # sampled tokens, which host-side scheduling needs next
+            toks = np.asarray(self.model.sample_greedy(logits))
+            return toks, obs.now_ns()
 
     def _index_insert(self, toks, slot: int) -> None:
         """Register a freshly-prefilled context's blocks with the prefix
@@ -770,14 +796,18 @@ class Engine:
             args.append(self._put(self.bm.table[slots]))
         self.cache = self._scatter(self.cache, group_cache, *args)
 
-    def _install(self, req: ServeRequest, slot: int, first_tok) -> None:
-        """Post-prefill bookkeeping shared by all admission paths."""
+    def _install(self, req: ServeRequest, slot: int, first_tok,
+                 t_ns: int) -> None:
+        """Post-prefill bookkeeping shared by all admission paths;
+        ``t_ns`` is when the sampled token reached the host."""
         self.slots[slot] = req
         self.stats.prefills += 1
         if not req.generated:        # fresh request: prefill emits 1st token
             req.generated.append(int(first_tok))
+            req.first_token_ns = t_ns
             self.stats.tokens_out += 1
         if req.done:
+            req.finish_ns = t_ns
             self.slots[slot] = None
             self._free_blocks(slot)
             self._admit_finished.append(req)
@@ -804,7 +834,8 @@ class Engine:
                                              ring=False)
         self._scatter_cache_legacy(slot, one, len(toks))
         self.stats.prefill_batches += 1
-        self._install(req, slot, self.model.sample_greedy(logits)[0])
+        first = int(self.model.sample_greedy(logits)[0])
+        self._install(req, slot, first, obs.now_ns())
 
     def _scatter_cache_legacy(self, slot: int, one: Dict,
                               ctx_len: int) -> None:
@@ -857,6 +888,7 @@ class Engine:
             chunk = np.zeros((g, c), np.int32)
             last_idx = np.zeros((g,), np.int32)
             rem = np.zeros((g,), np.int32)
+            rows = tokens = 0
             for j, m in enumerate(grp.members):
                 if m.done:
                     continue        # finished early: row computes pad zeros
@@ -864,6 +896,7 @@ class Engine:
                 chunk[j, :end - grp.base] = m.tokens[grp.base:end]
                 last_idx[j] = min(c - 1, len(m.tokens) - 1 - grp.base)
                 rem[j] = end - grp.base
+                rows, tokens = rows + 1, tokens + end - grp.base
             if self.bm is not None:
                 # snapshot the members' table rows; finished members (whose
                 # slots now decode, or may even have been reused) are routed
@@ -878,32 +911,33 @@ class Engine:
                             # (sanitizer-armed debug path only)
                             hi = grp.base + int(rem[j])
                             self.bm.check_write(m.slot, grp.base, hi)
-                logits, self.cache = self._run(
-                    self._chunk_paged, self.params, self.cache,
-                    self._put(chunk), self._put(np.int32(grp.base)),
-                    self._put(last_idx), self._put(rem), self._put(tbls))
-                self.stats.chunk_direct += 1
-            else:
-                if grp.cache is None:
-                    grp.cache = self._chunk_init(g)
-                logits, grp.cache = self._chunk(
-                    self.params, grp.cache, self._put(chunk),
-                    self._put(np.int32(grp.base)), self._put(last_idx))
+            with obs.span("serve.chunk", rows=rows, tokens=tokens,
+                          base=grp.base):
+                if self.bm is not None:
+                    logits, self.cache = self._run(
+                        self._chunk_paged, self.params, self.cache,
+                        self._put(chunk), self._put(np.int32(grp.base)),
+                        self._put(last_idx), self._put(rem), self._put(tbls))
+                    self.stats.chunk_direct += 1
+                else:
+                    if grp.cache is None:
+                        grp.cache = self._chunk_init(g)
+                    logits, grp.cache = self._chunk(
+                        self.params, grp.cache, self._put(chunk),
+                        self._put(np.int32(grp.base)), self._put(last_idx))
             self.stats.prefill_chunks += 1
             grp.base += c
             finishers = [(j, m) for j, m in enumerate(grp.members)
                          if not m.done and grp.base >= len(m.tokens)]
             if finishers:
-                # jaxlint: disable=host-sync -- intended: finishers' first
-                # tokens must land on the host to fill req.generated
-                first = np.asarray(self.model.sample_greedy(logits))
-                self._finish_pending(grp, finishers, first)
+                first, t_ns = self._sample(logits, "first")
+                self._finish_pending(grp, finishers, first, t_ns)
             if not all(m.done for m in grp.members):
                 still.append(grp)
         self._pending = still
 
-    def _finish_pending(self, grp: _PendingGroup, finishers, first
-                        ) -> None:
+    def _finish_pending(self, grp: _PendingGroup, finishers, first,
+                        t_ns: int) -> None:
         """Finish fully-prefilled members. Paged groups already wrote every
         chunk in place through the block tables — only the per-slot cache
         positions need setting; contig groups scatter out of the transient
@@ -921,7 +955,7 @@ class Engine:
             m.done = True
             self.slots[m.slot] = None     # _install re-marks the slot
             self._index_insert(list(m.tokens), m.slot)
-            self._install(m.req, m.slot, first[j])
+            self._install(m.req, m.slot, first[j], t_ns)
 
     # -- decode-time grow / preemption ------------------------------------------
     def _victim_cost(self, slot: int) -> float:
@@ -1053,30 +1087,32 @@ class Engine:
         if not live:
             return finished
         if self._lazy:           # upfront allocations can never need a grow
-            live = self._ensure_grow(live)
+            with obs.span("serve.grow"):
+                live = self._ensure_grow(live)
             if not live:
                 return finished
-        tokens = np.zeros((self.max_batch, 1), np.int32)
-        mask = np.zeros((self.max_batch,), bool)
-        for i in live:
-            tokens[i, 0] = self.slots[i].generated[-1]
-            mask[i] = True
-        if self.bm is not None and self.bm.sanitize:
+        with obs.span("serve.decode", rows=len(live)):
+            tokens = np.zeros((self.max_batch, 1), np.int32)
+            mask = np.zeros((self.max_batch,), bool)
             for i in live:
-                # this dispatch reads each live slot's KV history and
-                # writes the incoming token at position ctx_len - 1
-                self.bm.check_read(i, self.slots[i].ctx_len - 1)
-                self.bm.check_write(i, self.slots[i].ctx_len - 1,
-                                    self.slots[i].ctx_len)
-        self._sync_block_tbl()
-        logits, self.cache = self._run(self._decode, self.params,
-                                       self.cache, self._put(tokens),
-                                       self._put(mask))
-        # jaxlint: disable=host-sync -- intended: THE per-step sync point.
-        # Sampled tokens feed the next step's host-side scheduling; every
-        # other sync in step() has been eliminated, so the pipeline stalls
-        # exactly once per decode step.
-        nxt = np.asarray(self.model.sample_greedy(logits))[:, 0]
+                tokens[i, 0] = self.slots[i].generated[-1]
+                mask[i] = True
+            if self.bm is not None and self.bm.sanitize:
+                for i in live:
+                    # this dispatch reads each live slot's KV history and
+                    # writes the incoming token at position ctx_len - 1
+                    self.bm.check_read(i, self.slots[i].ctx_len - 1)
+                    self.bm.check_write(i, self.slots[i].ctx_len - 1,
+                                        self.slots[i].ctx_len)
+            self._sync_block_tbl()
+            logits, self.cache = self._run(self._decode, self.params,
+                                           self.cache, self._put(tokens),
+                                           self._put(mask))
+        # THE per-step sync point: sampled tokens feed the next step's
+        # host-side scheduling; every other sync in step() has been
+        # eliminated, so the pipeline stalls exactly once per decode step
+        nxt, t_ns = self._sample(logits, "decode")
+        nxt = nxt[:, 0]
         for i in live:
             req = self.slots[i]
             req.generated.append(int(nxt[i]))
@@ -1085,6 +1121,7 @@ class Engine:
                 # tokens in the cache == ctx_len - 1 (§5.1 invariant)
                 self.bm.note_live(i, req.ctx_len - 1)
             if req.done:
+                req.finish_ns = t_ns
                 finished.append(req)
                 self.slots[i] = None
                 self._free_blocks(i)
@@ -1096,9 +1133,10 @@ class Engine:
         (standalone operation; the global server normally drains
         ``take_preempted`` every round before this can fire)."""
         still: List[Tuple[ServeRequest, Dict]] = []
-        for req, payload in self._preempted:
-            if not self.import_kv(req, payload):
-                still.append((req, payload))
+        with obs.span("serve.readmit"):
+            for req, payload in self._preempted:
+                if not self.import_kv(req, payload):
+                    still.append((req, payload))
         self._preempted = still
 
     def take_preempted(self) -> List[Tuple[ServeRequest, Dict]]:
@@ -1148,7 +1186,6 @@ class Engine:
         self.bm.check_read(slot, pos)      # no-op unless sanitize mode
         nb = -(-pos // self.bm.block_size) if pos > 0 else 0
         ids = self._put(self.bm.table[slot, :nb].copy())
-        self.stats.kv_exports += 1
         return {"k": self.cache["k"][:, ids], "v": self.cache["v"][:, ids],
                 "pos": int(pos), "block_size": self.bm.block_size,
                 "arch": self.cfg.name}
@@ -1201,6 +1238,8 @@ class Engine:
             self._put(payload["v"]).astype(self.cache["v"].dtype))
         self.cache["pos"] = self.cache["pos"].at[slot].set(payload["pos"])
         self.slots[slot] = req
+        if not req.admit_ns:
+            req.admit_ns = obs.now_ns()
         self.stats.kv_imports += 1
         return True
 

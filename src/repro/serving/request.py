@@ -22,6 +22,12 @@ class ServeRequest:
     # timestamps (virtual clock)
     first_token_s: float = -1.0
     finish_s: float = -1.0
+    # stamps on the host clock (serving/obs.now_ns), 0 = not yet; each is
+    # set once, so a migrated or preempted request keeps its first values
+    submit_ns: int = 0          # GlobalServer.submit
+    admit_ns: int = 0           # Engine.admit_many (or a KV attach) took it
+    first_token_ns: int = 0     # its first token reached the host
+    finish_ns: int = 0          # its last token reached the host
 
     @property
     def ctx_len(self) -> int:

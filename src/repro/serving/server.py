@@ -57,6 +57,7 @@ import dataclasses
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from repro.configs.base import ArchConfig
+from repro.serving import obs
 from repro.serving.engine import Engine
 from repro.serving.request import ServeRequest
 from repro.serving.tensor_store import TensorStore
@@ -275,39 +276,42 @@ class GlobalServer:
         return out
 
     def submit(self, req: ServeRequest) -> Optional[ServingPipeline]:
-        alive = [p for p in self.pipelines if p.alive]
-        if not alive:
-            return None
-        b = self.bucket_for(req) \
-            if self.dispatch in ("throughput", "cost") else None
-        w = {p.pid: self._dispatch_weight(p, b) for p in alive}
-        if all(v <= 0 for v in w.values()):
-            # the estimator says no alive pipeline can serve this bucket
-            # (or every weight degenerated): fall back to scalar weights —
-            # the request must still be placed somewhere
-            w = {p.pid: max(p.weight, 1e-9) for p in alive}
-        key = (lambda pid: (pid, b)) if b is not None else (lambda pid: pid)
-        for p in alive:
-            self._rr_credit[key(p.pid)] = \
-                self._rr_credit.get(key(p.pid), 0.0) + w[p.pid]
-        best = max(alive, key=lambda p: self._rr_credit[key(p.pid)])
-        if self.use_prefix_share:
-            # tie-break toward a pipeline already holding this prompt's
-            # prefix: a holder within prefix_affinity_frac of the chosen
-            # pipeline's weight skips the prefix recompute entirely, which
-            # is worth a marginal estimated-throughput gap. Credits are
-            # still settled below, so long-run shares stay proportional.
-            holders = self._prefix_holders(req.prompt)
-            if holders and best.pid not in holders:
-                cand = [p for p in alive if p.pid in holders
-                        and w[p.pid] >= self.prefix_affinity_frac
-                        * w[best.pid]]
-                if cand:
-                    best = max(cand,
-                               key=lambda p: self._rr_credit[key(p.pid)])
-        self._rr_credit[key(best.pid)] -= sum(w.values())
-        best.queue.append(req)
-        return best
+        with obs.span("serve.submit", rid=req.rid):
+            if not req.submit_ns:
+                req.submit_ns = obs.now_ns()
+            alive = [p for p in self.pipelines if p.alive]
+            if not alive:
+                return None
+            b = self.bucket_for(req) \
+                if self.dispatch in ("throughput", "cost") else None
+            w = {p.pid: self._dispatch_weight(p, b) for p in alive}
+            if all(v <= 0 for v in w.values()):
+                # the estimator says no alive pipeline can serve this bucket
+                # (or every weight degenerated): fall back to scalar weights —
+                # the request must still be placed somewhere
+                w = {p.pid: max(p.weight, 1e-9) for p in alive}
+            key = (lambda pid: (pid, b)) if b is not None else (lambda pid: pid)
+            for p in alive:
+                self._rr_credit[key(p.pid)] = \
+                    self._rr_credit.get(key(p.pid), 0.0) + w[p.pid]
+            best = max(alive, key=lambda p: self._rr_credit[key(p.pid)])
+            if self.use_prefix_share:
+                # tie-break toward a pipeline already holding this prompt's
+                # prefix: a holder within prefix_affinity_frac of the chosen
+                # pipeline's weight skips the prefix recompute entirely, which
+                # is worth a marginal estimated-throughput gap. Credits are
+                # still settled below, so long-run shares stay proportional.
+                holders = self._prefix_holders(req.prompt)
+                if holders and best.pid not in holders:
+                    cand = [p for p in alive if p.pid in holders
+                            and w[p.pid] >= self.prefix_affinity_frac
+                            * w[best.pid]]
+                    if cand:
+                        best = max(cand,
+                                   key=lambda p: self._rr_credit[key(p.pid)])
+            self._rr_credit[key(best.pid)] -= sum(w.values())
+            best.queue.append(req)
+            return best
 
     # -- serving loop -------------------------------------------------------------
     _KV_MODEL = "__kv__"
@@ -336,19 +340,20 @@ class GlobalServer:
         if not self.use_prefix_share or self.store is None:
             return
         eng = p.engine
-        for run in eng.hot_runs(self.prefix_hot_hits):
-            # the run lives in this engine's own index — record the
-            # pipeline as a holder for prefix-affinity dispatch
-            self._prefix_home.setdefault(tuple(run), set()).add(p.pid)
-            key = self._prefix_key(self.cfg.name, eng.bm.block_size, run)
-            # peek (not contains): an already-published hot prefix counts
-            # as a store HIT, feeding the store's top-k hot-key pinning
-            if self.store.peek(self._PREFIX_MODEL, key) is not None:
-                continue
-            payload = eng.export_prefix(run)
-            if payload is not None:
-                self.store.put(self._PREFIX_MODEL, key, payload)
-                self.events.append((self.clock, "prefix_publish", key))
+        with obs.span("serve.prefix_publish"):
+            for run in eng.hot_runs(self.prefix_hot_hits):
+                # the run lives in this engine's own index — record the
+                # pipeline as a holder for prefix-affinity dispatch
+                self._prefix_home.setdefault(tuple(run), set()).add(p.pid)
+                key = self._prefix_key(self.cfg.name, eng.bm.block_size, run)
+                # peek (not contains): an already-published hot prefix
+                # counts as a store HIT, feeding top-k hot-key pinning
+                if self.store.peek(self._PREFIX_MODEL, key) is not None:
+                    continue
+                payload = eng.export_prefix(run)
+                if payload is not None:
+                    self.store.put(self._PREFIX_MODEL, key, payload)
+                    self.events.append((self.clock, "prefix_publish", key))
 
     def _warm_prefixes(self, p: ServingPipeline) -> None:
         """Warm a (new or rebuilt) pipeline's cache with every published
@@ -379,17 +384,19 @@ class GlobalServer:
         by attaching them (no recompute). Successful imports consume the
         payload; failures leave the request queued for the normal path."""
         rest: List[ServeRequest] = []
-        for r in p.queue:
-            key = self._kv_key(r)
-            payload = self.store.take(self._KV_MODEL, key)  # single consumer
-            if payload is None:
-                rest.append(r)
-            elif p.engine.import_kv(r, payload):
-                self.events.append((self.clock, "kv_attach", key))
-            else:
-                # incompatible here; republish for a later/other pipeline
-                self.store.put(self._KV_MODEL, key, payload)
-                rest.append(r)
+        with obs.span("serve.kv_attach"):
+            for r in p.queue:
+                key = self._kv_key(r)
+                # single consumer
+                payload = self.store.take(self._KV_MODEL, key)
+                if payload is None:
+                    rest.append(r)
+                elif p.engine.import_kv(r, payload):
+                    self.events.append((self.clock, "kv_attach", key))
+                else:
+                    # incompatible here; republish for a later/other pipeline
+                    self.store.put(self._KV_MODEL, key, payload)
+                    rest.append(r)
         p.queue[:] = rest
 
     def _drain_preempted(self, p: ServingPipeline) -> None:
@@ -397,18 +404,22 @@ class GlobalServer:
         found the pool dry: publish their KV payloads (so re-admission
         attaches instead of recomputing — same store path the grace window
         uses) and requeue them at the FRONT of the pipeline's queue."""
-        for req, payload in reversed(p.engine.take_preempted()):
-            self.events.append((self.clock, "preempt", f"r{req.rid}"))
-            # a victim preempted in its admission round has left the
-            # engine's live set before step()'s first-token scan runs:
-            # record TTFT here, at the round its token was emitted
-            if req.first_token_s < 0 and req.generated:
-                req.first_token_s = self.clock
-            if self.use_kv_migration and self.store is not None:
-                self._publish_kv(self._kv_key(req), payload)
-            # without a store the payload is dropped; generated tokens are
-            # preserved, so re-admission recomputes (§5.1 semantics)
-            p.queue.insert(0, req)
+        preempted = p.engine.take_preempted()
+        if not preempted:
+            return
+        with obs.span("serve.preempt"):
+            for req, payload in reversed(preempted):
+                self.events.append((self.clock, "preempt", f"r{req.rid}"))
+                # a victim preempted in its admission round has left the
+                # engine's live set before step()'s first-token scan runs:
+                # record TTFT here, at the round its token was emitted
+                if req.first_token_s < 0 and req.generated:
+                    req.first_token_s = self.clock
+                if self.use_kv_migration and self.store is not None:
+                    self._publish_kv(self._kv_key(req), payload)
+                # without a store the payload is dropped; generated tokens
+                # are preserved, so re-admission recomputes (§5.1)
+                p.queue.insert(0, req)
 
     def step(self) -> int:
         """One scheduling round: batched admission of queued requests (KV
@@ -416,33 +427,40 @@ class GlobalServer:
         pipeline, then publish + requeue any pool-preempted requests.
         Returns tokens emitted."""
         emitted = 0
-        for p in self.pipelines:
-            if not p.alive:
-                if self.clock >= p.down_until:
-                    p.alive = True
-                    self.events.append((self.clock, "revive", f"p{p.pid}"))
-                else:
-                    continue
-            toks_before = p.engine.stats.tokens_out
-            if self.use_kv_migration and self.store is not None and p.queue:
-                self._admit_kv_attached(p)
-            admitted = p.engine.admit_many(p.queue)
-            if admitted:
-                # skip-ahead admission: admitted is not necessarily a
-                # queue prefix — remove by identity
-                taken = {id(r) for r in admitted}
-                p.queue[:] = [r for r in p.queue if id(r) not in taken]
-            fin = p.engine.step()
-            self._drain_preempted(p)
-            self._publish_hot_prefixes(p)
-            for r in list(p.engine.active()) + fin:
-                if r.first_token_s < 0 and r.generated:
-                    r.first_token_s = self.clock
-            emitted += p.engine.stats.tokens_out - toks_before
-            for r in fin:
-                r.finish_s = self.clock
-                self.completed.append(r)
+        with obs.span("serve.step"):
+            for p in self.pipelines:
+                if not p.alive:
+                    if self.clock >= p.down_until:
+                        p.alive = True
+                        self.events.append((self.clock, "revive",
+                                            f"p{p.pid}"))
+                    else:
+                        continue
+                with obs.span("serve.pipeline", pid=p.pid):
+                    emitted += self._step_pipeline(p)
         return emitted
+
+    def _step_pipeline(self, p: ServingPipeline) -> int:
+        """One alive pipeline's part of a round; returns tokens emitted."""
+        toks_before = p.engine.stats.tokens_out
+        if self.use_kv_migration and self.store is not None and p.queue:
+            self._admit_kv_attached(p)
+        admitted = p.engine.admit_many(p.queue)
+        if admitted:
+            # skip-ahead admission: admitted is not necessarily a
+            # queue prefix — remove by identity
+            taken = {id(r) for r in admitted}
+            p.queue[:] = [r for r in p.queue if id(r) not in taken]
+        fin = p.engine.step()
+        self._drain_preempted(p)
+        self._publish_hot_prefixes(p)
+        for r in list(p.engine.active()) + fin:
+            if r.first_token_s < 0 and r.generated:
+                r.first_token_s = self.clock
+        for r in fin:
+            r.finish_s = self.clock
+            self.completed.append(r)
+        return p.engine.stats.tokens_out - toks_before
 
     def round_s(self) -> float:
         """Virtual seconds one scheduling round represents: the slowest

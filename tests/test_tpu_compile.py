@@ -5,10 +5,15 @@ Interpret mode cannot see the TPU compiler's tiling, layout and VMEM
 rules; these compiles can, at no chip time. Nothing here runs a kernel.
 The topology is described inside a fixture, never at import time: only
 one process may load the TPU library, and every test worker imports this
-module.
+module. Each compiled kernel must carry a name that the benchmark's
+roofline reader for it matches (``KERNELS`` in
+``chipbench/layer_metrics``), so a renamed kernel cannot drop out of its
+metric unnoticed.
 """
 
+import importlib
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +22,7 @@ import pytest
 from repro.configs import get_config
 from repro.kernels import attention as ka
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BLOCK = 16          # engine default pool block
 MAX_LEN = 2048      # max_len of the chip smoke run
 MAX_BATCH = 8
@@ -52,6 +58,23 @@ def _no_compile_cache():
     cc.reset_cache()
 
 
+def _reader_kernels(metric):
+    """The kernel kinds a roofline reader of the benchmark matches."""
+    if ROOT not in sys.path:
+        sys.path.insert(0, ROOT)
+    return importlib.import_module(
+        f"chipbench.layer_metrics.{metric}").KERNELS
+
+
+def _kernel_kinds(compiled):
+    """Kinds of the program's Pallas calls: ``%decode_attention_paged.1 =
+    ... custom-call(...), custom_call_target="tpu_custom_call"`` ->
+    ``decode_attention_paged``, as the profiler trace names the op."""
+    return {line.split(" = ")[0].strip().lstrip("%").split(".")[0]
+            for line in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line}
+
+
 def _geometry(arch):
     c = get_config(arch)
     return c.n_heads, c.n_kv_heads, c.hd
@@ -81,6 +104,8 @@ def test_paged_decode_compiles(one_chip, arch):
                         *_pool_shapes(nkv, d, MAX_BATCH),
                         ((MAX_BATCH,), jnp.int32))
     assert "tpu_custom_call" in compiled.as_text()
+    assert _kernel_kinds(compiled) & set(_reader_kernels(
+        "decode_attn_roofline"))
 
 
 def test_paged_chunk_compiles(one_chip):
@@ -94,6 +119,8 @@ def test_paged_chunk_compiles(one_chip):
                         *_pool_shapes(nkv, d, group),
                         ((), jnp.int32))
     assert "tpu_custom_call" in compiled.as_text()
+    assert _kernel_kinds(compiled) & set(_reader_kernels(
+        "prefill_attn_roofline"))
 
 
 def test_flash_prefill_compiles(one_chip):
@@ -107,3 +134,5 @@ def test_flash_prefill_compiles(one_chip):
                         ((group, seq, nkv, d), jnp.bfloat16),
                         ((group, seq, nkv, d), jnp.bfloat16))
     assert "tpu_custom_call" in compiled.as_text()
+    assert _kernel_kinds(compiled) & set(_reader_kernels(
+        "prefill_attn_roofline"))

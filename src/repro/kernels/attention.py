@@ -13,11 +13,17 @@ The KV history is either
   backing virtual positions ``[ik*block, (ik+1)*block)`` of row ``b`` and
   no gathered page view is ever materialized.
 
-The five entry points are cases of that one body: ``flash_attention``
+Four entry points are cases of that one body: ``flash_attention``
 (prefill, bases 0, optionally non-causal), ``chunk_attention`` /
 ``chunk_attention_paged`` (chunked prefill and the prefix-share suffix
-path, scalar or per-row bases) and ``decode_attention`` /
-``decode_attention_paged`` (C=1, bases = the current token's position).
+path, scalar or per-row bases) and ``decode_attention`` (C=1, bases = the
+current token's position). ``decode_attention_paged`` has a kernel of
+its own: one grid step per row, whose live pages (its length comes from
+its position and block table) a loop copies from the pool in HBM, many
+blocks per double-buffered DMA step, so no grid step is spent on a block
+past a row's position or on a dead row. A pool the compiler cannot copy
+that way (head size not 128, odd 16-bit KV head count) takes the block
+walk of the body above.
 
 Layout rules of the TPU compiler shape the specs. A block's last two dims
 must be multiples of (8, 128) or span the whole array, so every block
@@ -28,11 +34,11 @@ back by the wrapper. Scalars (bases, block tables) live in SMEM via
 scalar prefetch; the running max and denominator live in lane-broadcast
 ``(rows, 128)`` VMEM scratch, never as scalar stores into VMEM.
 
-Grid ``(B, C/block_q, n_kv_blocks)``; the KV axis is innermost and
-sequential. KV blocks no query of the tile can see (past the causal
-horizon, before the window) are skipped: their index map repeats the
-nearest visible block, so the pipeline issues no new DMA, and the body is
-predicated off.
+The body's grid is ``(B, C/block_q, n_kv_blocks)``; the KV axis is
+innermost and sequential. KV blocks no query of the tile can see (past
+the causal horizon, before the window) are skipped: their index map
+repeats the nearest visible block, so the pipeline issues no new DMA,
+and the body is predicated off.
 
 Debug ``probe`` mode (KV sanitizer): an extra ``(B, 1, 128)`` output
 carries the max |K|/|V| magnitude seen at *readable* positions; the ops
@@ -53,6 +59,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128
+DECODE_TOKENS = 512      # KV tokens one DMA step of paged decode aims at
+DECODE_VMEM = 8 << 20    # VMEM bytes for its double-buffered K/V slabs
 
 
 def _visible_blocks(base, iq, *, causal: bool, window: Optional[int],
@@ -296,15 +304,200 @@ def decode_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
                    probe=False, interpret=interpret)
 
 
+def _decode_pages(block: int, nkv: int, d: int, dtype,
+                  max_blocks: int) -> int:
+    """Pool blocks per DMA step of the paged decode kernel: enough for
+    ``DECODE_TOKENS`` tokens, fewer where the K and V slabs, double
+    buffered, would pass ``DECODE_VMEM``."""
+    page = block * nkv * d * jnp.dtype(dtype).itemsize
+    return max(1, min(DECODE_TOKENS // block, DECODE_VMEM // (4 * page),
+                      max_blocks))
+
+
+def _head_slabs(buf, slot, nkv: int, span: int):
+    """Buffer ``slot`` of a K or V slab, ``(span * nkv, d)`` with each
+    token's nkv head rows together, as per-head ``(span, d)`` tiles in
+    head order. 16-bit heads sit in packed row pairs (nkv is even): one
+    strided 32-bit load reads two heads and shifts split them, about
+    twice as fast on a v5e as a strided load of single 16-bit rows."""
+    if buf.dtype.itemsize != 2:
+        return [buf[slot, pl.ds(h, span, stride=nkv), :] for h in range(nkv)]
+    words = buf.bitcast(jnp.uint32)
+    heads = []
+    for j in range(nkv // 2):
+        u = words[slot, pl.ds(j, span, stride=nkv // 2), :]
+        heads += [pltpu.bitcast(bits, jnp.float32).astype(buf.dtype)
+                  for bits in (u << 16, u & jnp.uint32(0xFFFF0000))]
+    return heads
+
+
+def _decode_paged_kernel(len_ref, tbl_ref, q_ref, k_hbm, v_hbm, *refs,
+                         scale: float, window: Optional[int], nkv: int,
+                         block: int, pages: int, probe: bool):
+    if probe:
+        o_ref, p_ref, kbuf, vbuf, sem, m_scr, l_scr, acc_scr = refs
+    else:
+        (o_ref, kbuf, vbuf, sem, m_scr, l_scr, acc_scr), p_ref = refs, None
+    b = pl.program_id(0)
+    length = len_ref[b]                       # 0: a dead or pending row
+    pos = length - 1
+    first = (jnp.maximum(pos - window + 1, 0) // block
+             if window is not None else 0)
+    n_pages = jnp.where(length > 0, pos // block - first + 1, 0)
+    n_steps = (n_pages + pages - 1) // pages
+    span = pages * block
+    page_rows = block * nkv
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    if probe:
+        p_ref[...] = jnp.zeros_like(p_ref)
+
+    def dma(i, slot, start: bool):
+        """Step ``i``'s copies, one per live pool block of K and of V,
+        into buffer ``slot``; pages past the row's last are never read."""
+        def page(j, carry):
+            # a wait needs only the copy's size and semaphore
+            blk = tbl_ref[b, first + i * pages + j] if start else 0
+            at = pl.ds(pl.multiple_of(j * page_rows, page_rows), page_rows)
+            for hbm, buf in ((k_hbm, kbuf), (v_hbm, vbuf)):
+                cp = pltpu.make_async_copy(hbm.at[blk], buf.at[slot, at],
+                                           sem.at[slot])
+                if start:
+                    cp.start()
+                else:
+                    cp.wait()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(pages, n_pages - i * pages), page,
+                          0)
+
+    def attend(i, slot, last: bool):
+        t0 = (first + i * pages) * block
+        kpos = t0 + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+        mask = kpos <= pos
+        rows = t0 + jax.lax.broadcasted_iota(jnp.int32, (span, 1), 0)
+        if window is not None:
+            mask &= kpos > pos - window
+        if probe:
+            readable = rows <= pos
+            if window is not None:
+                readable &= rows > pos - window
+            mag = jnp.zeros((span, 1), jnp.float32)
+        for h, (k, v) in enumerate(zip(_head_slabs(kbuf, slot, nkv, span),
+                                       _head_slabs(vbuf, slot, nkv, span))):
+            if probe:
+                for x in (k, v):
+                    mag = jnp.maximum(mag, jnp.max(
+                        jnp.abs(x.astype(jnp.float32)), axis=1,
+                        keepdims=True))
+            if last:
+                # the tail past pos holds pages never copied: a masked
+                # weight of 0 times a stale NaN would still be NaN
+                v = jnp.where(rows <= pos, v, jnp.zeros_like(v))
+            q = q_ref[h]                                 # (group, d)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(mask, s * scale, NEG_INF)
+            m_prev = m_scr[h]                            # (group, LANES)
+            m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_next)
+            p = jnp.where(mask, jnp.exp(s - m_next[:, :1]), 0.0)
+            l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha[:, :1] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_scr[h] = m_next
+        if probe:
+            worst = jnp.max(jnp.where(readable, mag, 0.0))
+            p_ref[...] = jnp.maximum(p_ref[...], worst)
+
+    @pl.when(n_steps > 0)
+    def _first():
+        dma(0, 0, start=True)
+
+    def full_step(i, carry):
+        slot = i % 2
+        dma(i + 1, 1 - slot, start=True)
+        dma(i, slot, start=False)
+        attend(i, slot, last=False)
+        return carry
+
+    jax.lax.fori_loop(0, n_steps - 1, full_step, 0)
+
+    @pl.when(n_steps > 0)
+    def _last():
+        i = n_steps - 1
+        dma(i, i % 2, start=False)
+        attend(i, i % 2, last=True)
+
+    for h in range(nkv):
+        denom = jnp.maximum(l_scr[h][:, :1], 1e-30)
+        o_ref[h] = (acc_scr[h] / denom).astype(o_ref.dtype)
+
+
 def decode_attention_paged(q: jax.Array, cache_k: jax.Array,
                            cache_v: jax.Array, block_tbl: jax.Array,
                            pos, *, window: Optional[int] = None,
                            probe: bool = False, interpret: bool = False):
     """q: (B,1,nh,d); cache_k/v: (n_blocks, block, nkv, d) pool;
     block_tbl: (B, max_blocks); pos scalar or (B,) — the position of the
-    current (already written) token per sequence. Returns o, or
-    (o, probe_max) when ``probe`` is armed."""
-    return _attend(q, cache_k, cache_v, pos, block_tbl,
-                   name="decode_attention_paged", causal=True,
-                   window=window, block_q=1, block_kv=0, probe=probe,
-                   interpret=interpret)
+    current (already written) token per sequence. A row whose table
+    starts at the trash block 0 is dead or pending: it reads nothing and
+    its output is 0. Returns o, or (o, probe_max) when ``probe`` is
+    armed."""
+    b, _, nh, d = q.shape
+    _, block, nkv, _ = cache_k.shape
+    assert nh % nkv == 0, (nh, nkv)
+    if d != LANES or (jnp.dtype(cache_k.dtype).itemsize == 2 and nkv % 2):
+        # the page loop needs what the TPU compiler takes: DMAs of whole
+        # 128-lane rows (not qwen2's head size 64) and 16-bit heads read in
+        # pairs; any other pool keeps the per-block grid walk
+        return _attend(q, cache_k, cache_v, pos, block_tbl,
+                       name="decode_attention_paged", causal=True,
+                       window=window, block_q=1, block_kv=0, probe=probe,
+                       interpret=interpret)
+    group = nh // nkv
+    tbl = block_tbl.astype(jnp.int32)
+    pages = _decode_pages(block, nkv, d, cache_k.dtype, tbl.shape[1])
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    lengths = jnp.where(tbl[:, 0] == 0, 0, pos + 1)
+    # per KV head, its ``group`` query rows: one (group, d) tile each,
+    # and the output in the same head-major shape
+    qg = q.reshape(b, nkv, group, d)
+    row_spec = pl.BlockSpec((None, nkv, group, d), lambda i, *_: (i, 0, 0, 0))
+    out_shape = [jax.ShapeDtypeStruct((b, nkv, group, d), q.dtype)]
+    out_specs = [row_spec]
+    if probe:
+        out_shape.append(jax.ShapeDtypeStruct((b, 1, LANES), jnp.float32))
+        out_specs.append(pl.BlockSpec((None, 1, LANES),
+                                      lambda i, *_: (i, 0, 0)))
+    span = pages * block
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[row_spec, pl.BlockSpec(memory_space=pltpu.HBM),
+                  pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=out_specs,
+        scratch_shapes=[pltpu.VMEM((2, span * nkv, d), cache_k.dtype),
+                        pltpu.VMEM((2, span * nkv, d), cache_v.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.VMEM((nkv, group, LANES), jnp.float32),
+                        pltpu.VMEM((nkv, group, LANES), jnp.float32),
+                        pltpu.VMEM((nkv, group, d), jnp.float32)],
+    )
+    kernel = functools.partial(
+        _decode_paged_kernel, scale=1.0 / math.sqrt(d), window=window,
+        nkv=nkv, block=block, pages=pages, probe=probe)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="decode_attention_paged",
+    )(lengths, tbl, qg, *(c.reshape(c.shape[0], block * nkv, d)
+                          for c in (cache_k, cache_v)))
+    o = out[0].reshape(b, 1, nh, d)
+    return (o, out[1]) if probe else o

@@ -1091,12 +1091,18 @@ class Engine:
                 live = self._ensure_grow(live)
             if not live:
                 return finished
-        with obs.span("serve.decode", rows=len(live)):
+        with obs.span("serve.decode", rows=len(live)) as sp:
             tokens = np.zeros((self.max_batch, 1), np.int32)
             mask = np.zeros((self.max_batch,), bool)
+            pages = 0
             for i in live:
                 tokens[i, 0] = self.slots[i].generated[-1]
                 mask[i] = True
+                if self.bm is not None:
+                    pages += self.bm.blocks_for(self.slots[i].ctx_len)
+            if self.bm is not None:
+                # the pool blocks the decode kernel walks in each layer
+                sp.set_metadata(pages=pages)
             if self.bm is not None and self.bm.sanitize:
                 for i in live:
                     # this dispatch reads each live slot's KV history and

@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import attention as ka
 from repro.kernels import ref as kref
 from repro.kernels.attention import (chunk_attention, chunk_attention_paged,
                                      decode_attention, decode_attention_paged,
@@ -166,6 +167,96 @@ def test_decode_paged_probe_flags_readable_poison():
     _, pmax = decode_attention_paged(q, pk, poisoned, tbl, pos,
                                      probe=True, interpret=True)
     assert float(jnp.max(pmax)) >= KV_POISON
+
+
+def _close(out, ref, dtype):
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "nh,nkv,d,window",
+    [
+        (4, 2, 128, None),        # group 2 at d=128 (internlm2-1.8b)
+        (14, 2, 64, None),        # group 7 at d=64 (qwen2-0.5b)
+        (4, 4, 128, None),        # MHA
+        (4, 2, 128, 520),         # SWA, window start past page 0
+    ])
+def test_decode_attention_paged_sweep(nh, nkv, d, window, dtype):
+    """Paged decode kernel == jnp paged oracle at per-row positions 0,
+    block-1, block and one whose length passes one DMA step of pages
+    without being a multiple of it, beside a dead row (table all trash
+    block 0) whose output must only be finite."""
+    block, mb = 16, 40
+    pages = ka._decode_pages(block, nkv, d, dtype, mb)
+    assert pages < mb
+    pk, pv, tbl = _pool(5, mb, block, nkv, d, dtype)
+    tbl = tbl.at[4].set(0)
+    long = pages * block + 37
+    pos = jnp.asarray([0, block - 1, block, long, 300], jnp.int32)
+    q = jnp.asarray(RNG.randn(5, 1, nh, d), dtype)
+    out = decode_attention_paged(q, pk, pv, tbl, pos, window=window,
+                                 interpret=True)
+    ref = mattn.decode_attention_paged(q, pk, pv, tbl, pos, window=window)
+    _close(out[:4], ref[:4], dtype)
+    assert np.isfinite(np.asarray(out[4], np.float32)).all()
+
+
+@pytest.mark.parametrize("window", [None, 520])
+def test_decode_paged_walk_stops_at_live_length(window):
+    """The kernel never reads a pool block outside a row's live pages.
+    Table entries outside them name blocks past the pool's end, which the
+    TPU interpreter refuses to read; every unread block, the trash block
+    among them, holds NaN, as does fresh VMEM there. Live rows still
+    equal the oracle on the clean pool and table, and no output (the
+    dead row's included) holds a NaN."""
+    from jax.experimental.pallas import tpu as pltpu
+    block, mb, nh, nkv, d = 16, 40, 4, 2, 128
+    pk, pv, tbl = _pool(4, mb, block, nkv, d, jnp.float32)
+    pages = ka._decode_pages(block, nkv, d, jnp.float32, mb)
+    pos = jnp.asarray([5, block, pages * block + 37, 100], jnp.int32)
+    q = jnp.asarray(RNG.randn(4, 1, nh, d), jnp.float32)
+    ref = mattn.decode_attention_paged(q, pk, pv, tbl, pos, window=window)
+    n_blocks = pk.shape[0]
+    tbl = np.asarray(tbl)
+    walk = np.full_like(tbl, n_blocks + 7)
+    walk[3, 0] = 0                        # a dead row: trash block first
+    read = np.zeros(n_blocks, bool)
+    for r in range(3):
+        p = int(pos[r])
+        first = max(p - window + 1, 0) // block if window else 0
+        live = slice(first, p // block + 1)
+        walk[r, live] = tbl[r, live]
+        read[tbl[r, live]] = True
+    assert not read[0]
+    unread = jnp.asarray(~read)[:, None, None, None]
+    pk, pv = jnp.where(unread, jnp.nan, pk), jnp.where(unread, jnp.nan, pv)
+    out = decode_attention_paged(q, pk, pv, jnp.asarray(walk), pos,
+                                 window=window,
+                                 interpret=pltpu.InterpretParams())
+    assert np.isfinite(np.asarray(out)).all()
+    _close(out[:3], ref[:3], jnp.float32)
+
+
+def test_decode_paged_probe_reads_only_live_positions():
+    """The probe of the page-loop kernel (d=128): poison past a row's
+    position, in its last page or past it, stays invisible; poison at a
+    position the row reads trips the KV_POISON threshold."""
+    from repro.serving.kv_blocks import KV_POISON
+    b, nh, nkv, d, block, mb = 2, 4, 2, 128, 16, 4
+    pk, pv, tbl = _pool(b, mb, block, nkv, d, jnp.float32)
+    q = jnp.asarray(RNG.randn(b, 1, nh, d), jnp.float32)
+    pos = jnp.asarray([block + 3, block * mb - 1], jnp.int32)
+    cold = pv.at[tbl[0, 1], 4:].set(-KV_POISON).at[tbl[0, 2]].set(KV_POISON)
+    _, pmax = decode_attention_paged(q, pk, cold, tbl, pos, probe=True,
+                                     interpret=True)
+    assert float(jnp.max(pmax)) < KV_POISON
+    hot = pk.at[tbl[0, 1], 3].set(KV_POISON)
+    _, pmax = decode_attention_paged(q, hot, pv, tbl, pos, probe=True,
+                                     interpret=True)
+    assert float(pmax[0, 0, 0]) >= KV_POISON
+    assert float(pmax[1, 0, 0]) < KV_POISON
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

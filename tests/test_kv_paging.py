@@ -108,6 +108,30 @@ def test_paged_pallas_kernel_matches_jnp(setup):
     assert gen(use_pallas=True) == gen()
 
 
+def test_paged_page_loop_kernel_matches_jnp():
+    """At head_dim 128 decode takes the kernel's per-row page loop (the
+    reduced configs' head_dim 16 takes the block walk): staggered
+    admissions leave dead and pending rows beside live ones, and the
+    greedy tokens still match the jnp paged engine."""
+    cfg = dataclasses.replace(get_config("internlm2-1.8b").reduced(),
+                              head_dim=128)
+    params = _params_for(cfg)
+
+    def gen(**kw):
+        eng = Engine(cfg, params, max_batch=3, max_len=96,
+                     kv_layout="paged", block_size=8, **kw)
+        rs = [ServeRequest(prompt=list(range(1, 5 + 9 * i)),
+                           max_new_tokens=4 + 3 * i) for i in range(3)]
+        eng.admit_many(rs[:2])
+        eng.step()
+        assert eng.admit_many(rs[2:])
+        eng.drain()
+        return [list(r.generated) for r in rs]
+    out = gen(use_pallas=True)
+    assert [len(g) for g in out] == [4, 7, 10]
+    assert out == gen()
+
+
 def test_model_prefill_and_chunk_into_paged_cache(setup):
     """Model-level threading: prefill/prefill_chunk write through block
     tables; a paged decode after either matches the contig decode."""

@@ -104,6 +104,9 @@ def test_stamps_lie_inside_their_spans_on_the_trace_clock(setup, tmp_path):
     assert chunks == [(0, 16), (16, 16), (32, 8)]
     assert [s[3]["rows"] for s in spans if s[0] == "serve.prefill"] == [1]
     assert max(s[3]["rows"] for s in spans if s[0] == "serve.decode") == 2
+    # pages: each live row's ceil(ctx_len / 16), what the kernel walks;
+    # with both rows live the 40-token prompt holds 3 blocks, the other 1
+    assert max(s[3]["pages"] for s in spans if s[0] == "serve.decode") == 4
     admits = [s[3]["n"] for s in spans if s[0] == "serve.admit"]
     assert sum(admits) == 2
 

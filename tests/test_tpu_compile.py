@@ -108,6 +108,24 @@ def test_paged_decode_compiles(one_chip, arch):
         "decode_attn_roofline"))
 
 
+def test_paged_decode_compiles_at_benchmark_shape(one_chip):
+    """The benchmark's own decode shape (internlm2-1.8b, ``max_batch`` 64,
+    ``max_len`` 4096, 3600 pool blocks): the page loop's VMEM budget is
+    checked where the cell runs."""
+    nh, nkv, d = _geometry("internlm2-1.8b")
+    batch, mb, n_blocks = 64, 4096 // BLOCK, 3600
+    pool = (n_blocks, BLOCK, nkv, d)
+
+    def step(q, k, v, tbl, pos):
+        return ka.decode_attention_paged(q, k, v, tbl, pos)
+    compiled = _compile(step, one_chip,
+                        ((batch, 1, nh, d), jnp.bfloat16),
+                        (pool, jnp.bfloat16), (pool, jnp.bfloat16),
+                        ((batch, mb), jnp.int32), ((batch,), jnp.int32))
+    assert _kernel_kinds(compiled) & set(_reader_kernels(
+        "decode_attn_roofline"))
+
+
 def test_paged_chunk_compiles(one_chip):
     nh, nkv, d = _geometry("internlm2-1.8b")
     group, chunk = 4, 512

@@ -22,8 +22,8 @@ its own: one grid step per row, whose live pages (its length comes from
 its position and block table) a loop copies from the pool in HBM, many
 blocks per double-buffered DMA step, so no grid step is spent on a block
 past a row's position or on a dead row. A pool the compiler cannot copy
-that way (head size not 128, odd 16-bit KV head count) takes the block
-walk of the body above.
+that way (head size not 128, float16, odd bfloat16 KV head count) takes
+the block walk of the body above.
 
 Layout rules of the TPU compiler shape the specs. A block's last two dims
 must be multiples of (8, 128) or span the whole array, so every block
@@ -317,10 +317,12 @@ def _decode_pages(block: int, nkv: int, d: int, dtype,
 def _head_slabs(buf, slot, nkv: int, span: int):
     """Buffer ``slot`` of a K or V slab, ``(span * nkv, d)`` with each
     token's nkv head rows together, as per-head ``(span, d)`` tiles in
-    head order. 16-bit heads sit in packed row pairs (nkv is even): one
+    head order. bfloat16 heads sit in packed row pairs (nkv is even): one
     strided 32-bit load reads two heads and shifts split them, about
-    twice as fast on a v5e as a strided load of single 16-bit rows."""
-    if buf.dtype.itemsize != 2:
+    twice as fast on a v5e as a strided load of single 16-bit rows. The
+    split widens each half into the top of a float32, which is exact for
+    bfloat16 alone; other dtypes load single rows."""
+    if buf.dtype != jnp.bfloat16:
         return [buf[slot, pl.ds(h, span, stride=nkv), :] for h in range(nkv)]
     words = buf.bitcast(jnp.uint32)
     heads = []
@@ -442,17 +444,21 @@ def decode_attention_paged(q: jax.Array, cache_k: jax.Array,
                            probe: bool = False, interpret: bool = False):
     """q: (B,1,nh,d); cache_k/v: (n_blocks, block, nkv, d) pool;
     block_tbl: (B, max_blocks); pos scalar or (B,) — the position of the
-    current (already written) token per sequence. A row whose table
-    starts at the trash block 0 is dead or pending: it reads nothing and
-    its output is 0. Returns o, or (o, probe_max) when ``probe`` is
-    armed."""
+    current (already written) token per sequence. In the page loop a row
+    whose table starts at the trash block 0 is dead or pending: it reads
+    nothing and its output is 0. Returns o, or (o, probe_max) when
+    ``probe`` is armed."""
     b, _, nh, d = q.shape
     _, block, nkv, _ = cache_k.shape
     assert nh % nkv == 0, (nh, nkv)
-    if d != LANES or (jnp.dtype(cache_k.dtype).itemsize == 2 and nkv % 2):
+    dt = jnp.dtype(cache_k.dtype)
+    if d != LANES or not (dt == jnp.float32
+                          or (dt == jnp.bfloat16 and nkv % 2 == 0)):
         # the page loop needs what the TPU compiler takes: DMAs of whole
-        # 128-lane rows (not qwen2's head size 64) and 16-bit heads read in
-        # pairs; any other pool keeps the per-block grid walk
+        # 128-lane rows (not qwen2's head size 64) and strided loads of
+        # 32-bit words (float32 rows, or bfloat16 heads in pairs); any
+        # other pool (float16, odd bfloat16 KV head counts) keeps the
+        # per-block grid walk
         return _attend(q, cache_k, cache_v, pos, block_tbl,
                        name="decode_attention_paged", causal=True,
                        window=window, block_q=1, block_kv=0, probe=probe,

@@ -1094,15 +1094,14 @@ class Engine:
         with obs.span("serve.decode", rows=len(live)) as sp:
             tokens = np.zeros((self.max_batch, 1), np.int32)
             mask = np.zeros((self.max_batch,), bool)
-            pages = 0
             for i in live:
                 tokens[i, 0] = self.slots[i].generated[-1]
                 mask[i] = True
-                if self.bm is not None:
-                    pages += self.bm.blocks_for(self.slots[i].ctx_len)
-            if self.bm is not None:
-                # the pool blocks the decode kernel walks in each layer
-                sp.set_metadata(pages=pages)
+            if self.bm is not None and obs.recording():
+                # the live rows' pool blocks (what the decode kernel's page
+                # loop reads per layer when no window is set)
+                sp.set_metadata(pages=sum(
+                    self.bm.blocks_for(self.slots[i].ctx_len) for i in live))
             if self.bm is not None and self.bm.sanitize:
                 for i in live:
                     # this dispatch reads each live slot's KV history and

@@ -49,10 +49,15 @@ class _Off:
 _OFF = _Off()
 
 
+def recording() -> bool:
+    """Whether a profiler session runs, so that spans record."""
+    return TraceAnnotation.is_enabled()
+
+
 def span(name: str, **meta):
     """A context manager over ``TraceAnnotation(name, **meta)`` while a
     profiler session runs, else the shared no-op span. Either one takes
     ``set_metadata(**meta)`` for what is known only at the span's end."""
-    if not TraceAnnotation.is_enabled():
+    if not recording():
         return _OFF
     return TraceAnnotation(name, **meta)

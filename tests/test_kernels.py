@@ -18,8 +18,11 @@ RNG = np.random.RandomState(42)
 
 
 def _tol(dtype):
-    return dict(atol=2e-2, rtol=2e-2) if dtype == jnp.bfloat16 \
-        else dict(atol=2e-5, rtol=2e-5)
+    if dtype == jnp.bfloat16:
+        return dict(atol=2e-2, rtol=2e-2)
+    if dtype == jnp.float16:
+        return dict(atol=5e-3, rtol=5e-3)
+    return dict(atol=2e-5, rtol=2e-5)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -174,23 +177,32 @@ def _close(out, ref, dtype):
                                np.asarray(ref, np.float32), **_tol(dtype))
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.float16])
 @pytest.mark.parametrize(
     "nh,nkv,d,window",
     [
         (4, 2, 128, None),        # group 2 at d=128 (internlm2-1.8b)
-        (14, 2, 64, None),        # group 7 at d=64 (qwen2-0.5b)
+        (16, 2, 128, None),       # group 8 at d=128
+        (14, 2, 64, None),        # group 7 at d=64 (qwen2-0.5b): block walk
         (4, 4, 128, None),        # MHA
         (4, 2, 128, 520),         # SWA, window start past page 0
     ])
-def test_decode_attention_paged_sweep(nh, nkv, d, window, dtype):
-    """Paged decode kernel == jnp paged oracle at per-row positions 0,
-    block-1, block and one whose length passes one DMA step of pages
-    without being a multiple of it, beside a dead row (table all trash
-    block 0) whose output must only be finite."""
+def test_decode_attention_paged_sweep(nh, nkv, d, window, dtype,
+                                      monkeypatch):
+    """Paged decode == jnp paged oracle at per-row positions 0, block-1,
+    block and one whose length passes one DMA step of pages without being
+    a multiple of it, beside a dead row (table all trash block 0) whose
+    output must only be finite. Head size 128 in float32 or bfloat16 runs
+    the page loop; head size 64 and float16 run the block walk, and each
+    case asserts which path it took."""
+    walks, attend = [], ka._attend
+
+    def block_walk(*a, **kw):
+        walks.append(kw["name"])
+        return attend(*a, **kw)
+    monkeypatch.setattr(ka, "_attend", block_walk)
     block, mb = 16, 40
     pages = ka._decode_pages(block, nkv, d, dtype, mb)
-    assert pages < mb
     pk, pv, tbl = _pool(5, mb, block, nkv, d, dtype)
     tbl = tbl.at[4].set(0)
     long = pages * block + 37
@@ -201,6 +213,10 @@ def test_decode_attention_paged_sweep(nh, nkv, d, window, dtype):
     ref = mattn.decode_attention_paged(q, pk, pv, tbl, pos, window=window)
     _close(out[:4], ref[:4], dtype)
     assert np.isfinite(np.asarray(out[4], np.float32)).all()
+    page_loop = d == 128 and dtype != jnp.float16
+    assert bool(walks) != page_loop
+    if page_loop:
+        assert pages < mb
 
 
 @pytest.mark.parametrize("window", [None, 520])

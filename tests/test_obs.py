@@ -34,6 +34,7 @@ def test_span_outside_a_session_is_one_shared_noop():
     with obs.span("serve.admit") as b:
         b.set_metadata(n=2)
     assert a is b
+    assert not obs.recording()
     assert abs(obs.now_ns() - obs.now_ns()) < 10**9
 
 
@@ -71,6 +72,7 @@ def test_stamps_lie_inside_their_spans_on_the_trace_clock(setup, tmp_path):
     opts.host_tracer_level = 2
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
+        assert obs.recording()
         for r in reqs:
             srv.submit(r)
         while srv.pending():
@@ -104,7 +106,7 @@ def test_stamps_lie_inside_their_spans_on_the_trace_clock(setup, tmp_path):
     assert chunks == [(0, 16), (16, 16), (32, 8)]
     assert [s[3]["rows"] for s in spans if s[0] == "serve.prefill"] == [1]
     assert max(s[3]["rows"] for s in spans if s[0] == "serve.decode") == 2
-    # pages: each live row's ceil(ctx_len / 16), what the kernel walks;
+    # pages: each live row's ceil(ctx_len / 16), its live pool blocks;
     # with both rows live the 40-token prompt holds 3 blocks, the other 1
     assert max(s[3]["pages"] for s in spans if s[0] == "serve.decode") == 4
     admits = [s[3]["n"] for s in spans if s[0] == "serve.admit"]

@@ -2,6 +2,9 @@
 against the plain float32 reference run over each prompt with its served
 tokens, after the window has closed and the program's state is freed.
 
+The reference is the forward of the configuration's model family, the
+module ``chipbench/families/<family>.py`` that the caller passes in.
+
 Two numbers are compared, each against its own limit in the cell file.
 A token's gap is how far the served token's reference logit lies below
 the reference's best logit at that position, in standard deviations of
@@ -19,6 +22,7 @@ by the same numbers and limits, and must come out not correct.
 
 from __future__ import annotations
 
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -60,16 +64,15 @@ def _widest(gaps: np.ndarray) -> float:
     return float(np.max(gaps))
 
 
-def compare(weights, dims, sample: List, quant: Optional[str] = None
-            ) -> Dict[str, float]:
+def compare(family: ModuleType, weights, dims, sample: List,
+            quant: Optional[str] = None) -> Dict[str, float]:
     """Widest gap (and, with ``quant``, the control's widest gap) over
-    every served token of the sample."""
-    from chipbench import reference
+    every served token of the sample, by ``family``'s reference."""
     allg: List[np.ndarray] = []
     allc: List[np.ndarray] = []
     for t in sample:
-        g = reference.gaps(weights, dims, t.req.prompt.tolist(),
-                           list(t.sreq.generated), quant)
+        g = family.gaps(weights, dims, t.req.prompt.tolist(),
+                        list(t.sreq.generated), quant)
         allg.append(g["gap_sd"])
         allc.append(g["control_gap_sd"])
     g = np.concatenate(allg) if allg else np.zeros(0)

@@ -6,9 +6,8 @@ kernel calls inside the decode program, for the server steps wholly
 inside the trace. The work comes from the live contexts the harness
 recorded, never from the slots or positions the kernel walks."""
 
-from chipbench import costs
 from chipbench import trace as tr
-from chipbench.readings import least_time
+from chipbench.reference import least_time
 
 PROGRAMS = ("jit_decode_fn",)
 
@@ -27,7 +26,7 @@ def read(r):
     secs = tr.op_time(r.trace, PROGRAMS, is_kernel, span)
     fl = by = 0.0
     for rec, _ in r.traced:
-        f, b = costs.decode_attn(r.dims, rec.decode_ctx)
+        f, b = r.family.decode_attn(r.dims, rec.decode_ctx)
         fl, by = fl + f, by + b
     if secs <= 0 or fl <= 0:
         return None

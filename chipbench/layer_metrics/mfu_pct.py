@@ -5,15 +5,14 @@ once plus the live K/V over HBM bandwidth, whichever is larger) over the
 sum of the steps' wall times on the host clock. A kernel taken off the
 path leaves this bound in place."""
 
-from chipbench import costs
-from chipbench.readings import least_time
+from chipbench.reference import least_time
 
 
 def read(r):
     least = wall = 0.0
     for s in r.steps:
         rows = len(s.decode_ctx) + s.first_tokens
-        fl, by = costs.step_work(r.dims, s.decode_ctx, s.pieces, rows)
+        fl, by = r.family.step_work(r.dims, s.decode_ctx, s.pieces, rows)
         if fl <= 0:
             continue
         least += least_time(fl, by, r.peaks)

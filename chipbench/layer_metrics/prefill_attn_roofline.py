@@ -5,9 +5,8 @@ device time of the attention kernel calls inside the prefill programs
 (bucketed prefill, chunked prefill into the pool, shared-prefix
 suffix)."""
 
-from chipbench import costs
 from chipbench import trace as tr
-from chipbench.readings import least_time
+from chipbench.reference import least_time
 
 PROGRAMS = ("jit_prefill_fn", "jit_chunk_paged_fn", "jit_suffix_fn")
 
@@ -26,7 +25,7 @@ def read(r):
     secs = tr.op_time(r.trace, PROGRAMS, is_kernel, span)
     fl = by = 0.0
     for rec, _ in r.traced:
-        f, b = costs.prefill_attn(r.dims, rec.pieces)
+        f, b = r.family.prefill_attn(r.dims, rec.pieces)
         fl, by = fl + f, by + b
     if secs <= 0 or fl <= 0:
         return None

@@ -6,8 +6,7 @@ larger) over the sum of those steps' wall times on the host clock. The
 first token of a request waits on these steps; a prefill kernel taken
 off the path leaves this bound in place."""
 
-from chipbench import costs
-from chipbench.readings import least_time
+from chipbench.reference import least_time
 
 
 def read(r):
@@ -16,7 +15,7 @@ def read(r):
         if not s.pieces:
             continue
         rows = len(s.decode_ctx) + s.first_tokens
-        fl, by = costs.step_work(r.dims, s.decode_ctx, s.pieces, rows)
+        fl, by = r.family.step_work(r.dims, s.decode_ctx, s.pieces, rows)
         least += least_time(fl, by, r.peaks)
         wall += s.t1 - s.t0
     return 100.0 * least / wall if wall > 0 else None

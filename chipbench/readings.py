@@ -12,6 +12,7 @@ from __future__ import annotations
 import dataclasses
 import importlib.util
 import os
+from types import ModuleType
 from typing import Callable, Dict, List, Optional, Tuple
 
 from chipbench import trace as tr
@@ -21,7 +22,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 
 @dataclasses.dataclass
 class Reading:
-    dims: object                    # reference.Dims of the configuration
+    dims: object                    # the family's Dims of the configuration
+    family: ModuleType              # chipbench/families/<family>.py
     peaks: Dict[str, float]
     max_batch: int                  # slots of one engine
     window_s: float
@@ -40,12 +42,6 @@ class Reading:
         if not self.traced:
             return None
         return self.traced[0][1][0], self.traced[-1][1][1]
-
-
-def least_time(flops: float, nbytes: float, peaks: Dict[str, float]
-               ) -> float:
-    """The least time the chip could take: compute or HBM, the larger."""
-    return max(flops / peaks["flops_bf16"], nbytes / peaks["hbm_bytes_s"])
 
 
 def reader(name: str) -> Callable[[Reading], Optional[float]]:

@@ -1,30 +1,27 @@
-"""Plain float32 reference of the dense decoder family, kept with the
-benchmark and independent of the program under test.
+"""What every model family's plain reference shares (the families are
+``chipbench/families/<family>.py``), kept with the benchmark and
+independent of the program under test.
 
-The family: token embedding, then per layer RMSNorm -> grouped-query
-attention (optional QKV bias, rotary embedding with ``rope_theta`` on the
-two halves of each head) -> residual -> RMSNorm -> SwiGLU
-(``silu(x W_gate) * (x W_up) W_down``) -> residual; a final RMSNorm and
-the output head (the embedding transposed when tied). Every matrix
-product runs in float32 under ``jax.default_matmul_precision("highest")``.
+The weights are defined by the seed: one key per tensor, split from the
+seed's key (``param_key``) in the order of the sorted tensor names of the
+family's weight tree, ``normal(0, 1) * 0.02`` rounded to the served dtype
+for matrices, ones for norms, zeros for biases (``init_weights``). The
+benchmark makes the served model from the same key; the family computes
+its own copy.
 
-The weights are defined by the seed through ``make_weights``: one key per
-tensor, split from the seed's key in the order of the sorted tensor names,
-``normal(0, 1) * 0.02`` rounded to the served dtype for matrices, ones for
-norms, zeros for biases, the vocabulary padded to ``vocab_pad``. The
-benchmark makes the served model from the same key and ``weight_tree``
-layout; this module computes its own copy.
+Every matrix product of a reference runs in float32 (``mm``) under
+``jax.default_matmul_precision("highest")``. ``quant="fp8"`` is the
+benchmark's control: every operand is rounded to float8 e4m3 with one
+scale (``fp8``), the precision step below the served bfloat16.
 
-``quant="fp8"`` is the benchmark's control: every matrix product takes
-operands rounded to float8 e4m3 (one scale per operand), the precision
-step below the served bfloat16.
+``served_gaps`` turns a family's forward into the numbers ``check.py``
+compares, and ``dtype_bytes`` and ``least_time`` serve the families'
+counts of useful work and the readers' rooflines.
 """
 
 from __future__ import annotations
 
-import dataclasses
-import functools
-from typing import Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -33,65 +30,10 @@ import numpy as np
 MASK32 = 0xFFFFFFFF
 
 
-@dataclasses.dataclass(frozen=True)
-class Dims:
-    n_layers: int
-    d_model: int
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    vocab_pad: int = 128
-    qkv_bias: bool = False
-    tied: bool = False
-    rope_theta: float = 10000.0
-    eps: float = 1e-5
-    dtype: str = "bfloat16"
-
-    @property
-    def padded_vocab(self) -> int:
-        return -(-self.vocab // self.vocab_pad) * self.vocab_pad
-
-    @classmethod
-    def from_config(cls, c: Dict) -> "Dims":
-        """Dims from a ``chipbench/configs/<config>.json`` file."""
-        return cls(n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-                   n_heads=c["num_attention_heads"],
-                   n_kv_heads=c["num_key_value_heads"],
-                   head_dim=c["head_dim"], d_ff=c["intermediate_size"],
-                   vocab=c["vocab_size"], qkv_bias=bool(c.get("qkv_bias")),
-                   tied=bool(c["tie_word_embeddings"]),
-                   rope_theta=float(c["rope_theta"]),
-                   eps=float(c["rms_norm_eps"]), dtype=c["torch_dtype"])
-
-
 def param_key(seed: int) -> jax.Array:
     """The key of ``--seed``: all of its bits count, not only the low 32."""
     key = jax.random.PRNGKey(seed & MASK32)
     return jax.random.fold_in(key, (seed >> 32) & MASK32)
-
-
-def weight_tree(dm: Dims) -> Dict:
-    """Nested dict of (shape, init) for every tensor; layers stacked."""
-    L, d, f = dm.n_layers, dm.d_model, dm.d_ff
-    q, kv = dm.n_heads * dm.head_dim, dm.n_kv_heads * dm.head_dim
-    attn = {"wq": ((L, d, q), "normal"), "wk": ((L, d, kv), "normal"),
-            "wv": ((L, d, kv), "normal"), "wo": ((L, q, d), "normal")}
-    if dm.qkv_bias:
-        attn.update(bq=((L, q), "zeros"), bk=((L, kv), "zeros"),
-                    bv=((L, kv), "zeros"))
-    tree = {"embed": {"tok": ((dm.padded_vocab, d), "normal")},
-            "final_norm": {"w": ((d,), "ones")},
-            "layers": {"attn": attn,
-                       "ln_attn": {"w": ((L, d), "ones")},
-                       "ln_mlp": {"w": ((L, d), "ones")},
-                       "mlp": {"w_up": ((L, d, f), "normal"),
-                               "w_down": ((L, f, d), "normal"),
-                               "w_gate": ((L, d, f), "normal")}}}
-    if not dm.tied:
-        tree["lm_head"] = ((d, dm.padded_vocab), "normal")
-    return tree
 
 
 def _leaves(tree: Dict, path=()):
@@ -103,10 +45,11 @@ def _leaves(tree: Dict, path=()):
             yield path + (k,), v
 
 
-def make_weights(dm: Dims, key: jax.Array) -> Dict:
-    """The seed's weights in the served dtype (call under ``jax.jit``)."""
-    dtype = jnp.dtype(dm.dtype)
-    leaves = list(_leaves(weight_tree(dm)))
+def init_weights(tree: Dict, dtype: str, key: jax.Array) -> Dict:
+    """The seed's weights of a nested dict of ``(shape, init)`` leaves, in
+    ``dtype`` (call under ``jax.jit``)."""
+    dtype = jnp.dtype(dtype)
+    leaves = list(_leaves(tree))
     keys = jax.random.split(key, len(leaves))
     out: Dict = {}
     for (path, (shape, init)), k in zip(leaves, keys):
@@ -121,92 +64,30 @@ def make_weights(dm: Dims, key: jax.Array) -> Dict:
     return out
 
 
-# -- forward -----------------------------------------------------------------
-def _fp8(x: jax.Array) -> jax.Array:
+def fp8(x: jax.Array) -> jax.Array:
     """Round to float8 e4m3 with one scale for the whole operand."""
     amax = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30)
     s = 448.0 / amax
     return (x * s).astype(jnp.float8_e4m3fn).astype(jnp.float32) / s
 
 
-def _mm(eq: str, a, b, quant: Optional[str]):
+def mm(eq: str, a, b, quant: Optional[str]):
     a, b = a.astype(jnp.float32), b.astype(jnp.float32)
     if quant == "fp8":
-        a, b = _fp8(a), _fp8(b)
+        a, b = fp8(a), fp8(b)
     return jnp.einsum(eq, a, b)
-
-
-def _rms(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
-
-
-def _rope(x, pos, theta):
-    """x: (S, n, hd); rotate the two halves of each head."""
-    half = x.shape[-1] // 2
-    freqs = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos[:, None].astype(jnp.float32) * freqs
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _layer(dm: Dims, quant, x, lw):
-    S = x.shape[0]
-    nh, nkv, hd = dm.n_heads, dm.n_kv_heads, dm.head_dim
-    a = lw["attn"]
-    f32 = lambda t: t.astype(jnp.float32)          # noqa: E731
-    h = _rms(x, f32(lw["ln_attn"]["w"]), dm.eps)
-    q = _mm("sd,dk->sk", h, a["wq"], quant)
-    k = _mm("sd,dk->sk", h, a["wk"], quant)
-    v = _mm("sd,dk->sk", h, a["wv"], quant)
-    if dm.qkv_bias:
-        q, k, v = q + f32(a["bq"]), k + f32(a["bk"]), v + f32(a["bv"])
-    pos = jnp.arange(S)
-    q = _rope(q.reshape(S, nh, hd), pos, dm.rope_theta)
-    k = _rope(k.reshape(S, nkv, hd), pos, dm.rope_theta)
-    v = v.reshape(S, nkv, hd)
-    g = nh // nkv
-    qg = q.reshape(S, nkv, g, hd)
-    s = _mm("skgd,tkd->kgst", qg, k, quant) / np.sqrt(hd)
-    causal = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
-    s = jnp.where(causal, s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    o = _mm("kgst,tkd->skgd", p, v, quant).reshape(S, nh * hd)
-    x = x + _mm("sk,kd->sd", o, a["wo"], quant)
-    m = lw["mlp"]
-    h = _rms(x, f32(lw["ln_mlp"]["w"]), dm.eps)
-    up = _mm("sd,df->sf", h, m["w_up"], quant)
-    gate = _mm("sd,df->sf", h, m["w_gate"], quant)
-    return x + _mm("sf,fd->sd", jax.nn.silu(gate) * up, m["w_down"], quant)
-
-
-@functools.partial(jax.jit, static_argnames=("dm", "quant"))
-def hidden(w: Dict, tokens: jax.Array, dm: Dims, quant=None) -> jax.Array:
-    """Final-norm hidden states (S, d) of one sequence, causal."""
-    with jax.default_matmul_precision("highest"):
-        x = jnp.take(w["embed"]["tok"], tokens, axis=0).astype(jnp.float32)
-
-        def body(x, lw):
-            return _layer(dm, quant, x, lw), None
-        x, _ = jax.lax.scan(body, x, w["layers"])
-        return _rms(x, w["final_norm"]["w"].astype(jnp.float32), dm.eps)
-
-
-@functools.partial(jax.jit, static_argnames=("dm", "quant"))
-def logits(w: Dict, h: jax.Array, dm: Dims, quant=None) -> jax.Array:
-    """(rows, vocab) float32 logits of hidden rows ``h`` (rows, d)."""
-    with jax.default_matmul_precision("highest"):
-        head = w["embed"]["tok"].T if dm.tied else w["lm_head"]
-        return _mm("sd,dv->sv", h, head, quant)[:, :dm.vocab]
 
 
 ROW_BLOCK = 256
 SEQ_BUCKET = 512
 
 
-def gaps(w: Dict, dm: Dims, prompt: Sequence[int], served: Sequence[int],
-         quant: Optional[str] = None) -> Dict[str, np.ndarray]:
-    """Reference logits at every position that produced a served token.
+def served_gaps(hidden: Callable, logits: Callable, w: Dict, dm,
+                prompt: Sequence[int], served: Sequence[int],
+                quant: Optional[str] = None) -> Dict[str, np.ndarray]:
+    """Reference logits at every position that produced a served token,
+    from a family's ``hidden(w, tokens, dm, quant)`` (final-norm hidden
+    states of one causal sequence) and ``logits(w, h, dm, quant)``.
 
     Returns per served token ``gap_sd``: how far the served token's
     reference logit lies below the reference's best, in standard
@@ -239,3 +120,14 @@ def gaps(w: Dict, dm: Dims, prompt: Sequence[int], served: Sequence[int],
                 (best - ref[np.arange(len(blk)), pick]) / sd)
     return {k: np.concatenate(v) if v else np.zeros(0)
             for k, v in out.items()}
+
+
+def dtype_bytes(dm) -> int:
+    """Bytes of one element of the served dtype ``dm.dtype``."""
+    return {"bfloat16": 2, "float16": 2, "float32": 4}[dm.dtype]
+
+
+def least_time(flops: float, nbytes: float, peaks: Dict[str, float]
+               ) -> float:
+    """The least time the chip could take: compute or HBM, the larger."""
+    return max(flops / peaks["flops_bf16"], nbytes / peaks["hbm_bytes_s"])

@@ -6,8 +6,9 @@
 The cell, its configuration, its traffic mix and its per-layer metrics are
 found by name from ``BENCHMARK.json``: ``chipbench/cells/<cell>.json``
 (rate, pre-roll, trace and check settings, replicas),
-``chipbench/configs/<config>.json``, ``chipbench/traffic/<mix>.json`` and
-``chipbench/layer_metrics/<metric>.py``.
+``chipbench/configs/<config>.json``, the model family that configuration
+names (``chipbench/families/<family>.py``), ``chipbench/traffic/<mix>.json``
+and ``chipbench/layer_metrics/<metric>.py``.
 
 Set-up (``setup_s``, process start to window open): params made on the
 chip from the seed, one ``GlobalServer`` pipeline per chip, a warm-up
@@ -18,7 +19,8 @@ loop on the host clock, with nothing compiled inside it. After it, chat
 cells keep stepping until every request that arrived in the window has
 its first token. Then ``memory_peak_bytes`` is read, the program's state
 freed, and the served tokens of a sample of requests compared with the
-plain float32 reference (``chipbench/check.py``).
+plain float32 reference of the configuration's model family
+(``chipbench/check.py``, ``chipbench/families/``).
 
 With ``--trace 0`` the result carries the cell's end-to-end metrics;
 with ``--trace 1`` a few seconds in the middle of the window are traced
@@ -124,22 +126,6 @@ def counters(srv) -> Dict[str, float]:
             for k in keys}
 
 
-def program_config(cfg, config: Dict) -> None:
-    """The program's registry entry must be the configuration file's."""
-    want = {"n_layers": config["num_hidden_layers"],
-            "d_model": config["hidden_size"],
-            "n_heads": config["num_attention_heads"],
-            "n_kv_heads": config["num_key_value_heads"],
-            "hd": config["head_dim"], "d_ff": config["intermediate_size"],
-            "vocab": config["vocab_size"],
-            "qkv_bias": bool(config.get("qkv_bias")),
-            "tie_embeddings": config["tie_word_embeddings"],
-            "rope_theta": config["rope_theta"], "dtype": config["torch_dtype"]}
-    got = {k: getattr(cfg, k) for k in want}
-    if got != want:
-        raise RunError(f"program config {got} differs from the file {want}")
-
-
 def main(argv: Optional[List[str]] = None, rehearse: bool = False,
          fault=None) -> int:
     """``rehearse`` (tests only): the reduced config on whatever backend
@@ -177,7 +163,13 @@ def main(argv: Optional[List[str]] = None, rehearse: bool = False,
 
 
 def run(args, rehearse: bool, fault) -> int:
+    from chipbench import families
     data = load_cell(args.workload)
+    config = data["config"]
+    try:
+        family = families.load(config)
+    except LookupError as e:
+        raise RunError(str(e))
     try:
         import jax
         from repro.configs import get_config
@@ -192,7 +184,12 @@ def run(args, rehearse: bool, fault) -> int:
     from chipbench.readings import Reading, reader
     from chipbench.traffic import generator
 
-    w, cell, config = data["workload"], data["cell"], data["config"]
+    w, cell = data["workload"], data["cell"]
+    cfg = get_config(config["registry_key"])
+    differ = family.check_program(cfg, config)
+    if differ:
+        raise RunError(f"the program's registry entry {cfg.name!r} differs "
+                       f"from the configuration file at {differ}")
     chips = int(w["chips"])
     devices = jax.devices()
     dev0 = devices[0]
@@ -206,19 +203,12 @@ def run(args, rehearse: bool, fault) -> int:
         log(f"compile cache: {enable_cache(jax)}")
     compiles = CompileCounter(jax.monitoring)
 
-    cfg = get_config(config["registry_key"])
     if rehearse:
         cfg = cfg.reduced()
-        dims = reference.Dims(
-            n_layers=cfg.n_layers, d_model=cfg.d_model, n_heads=cfg.n_heads,
-            n_kv_heads=cfg.n_kv_heads, head_dim=cfg.hd, d_ff=cfg.d_ff,
-            vocab=cfg.vocab, vocab_pad=cfg.vocab_pad_multiple,
-            qkv_bias=cfg.qkv_bias, tied=cfg.tie_embeddings,
-            rope_theta=cfg.rope_theta, dtype=cfg.dtype)
+        dims = family.Dims.from_program(cfg)
         pk = {"flops_bf16": 197e12, "hbm_bytes_s": 819e9}
     else:
-        program_config(cfg, config)
-        dims = reference.Dims.from_config(config)
+        dims = family.Dims.from_config(config)
     geo = serve.geometry(config, rehearse)
     replicas = int(cell.get("replicas", chips))
 
@@ -357,7 +347,7 @@ def run(args, rehearse: bool, fault) -> int:
         pairs = [(s, sp_) for s, sp_ in zip(traced_recs, spans)
                  if win[0] <= sp_[0] and sp_[1] <= win[1]]
         reading = Reading(
-            dims=dims, peaks=pk, max_batch=geo["max_batch"],
+            dims=dims, family=family, peaks=pk, max_batch=geo["max_batch"],
             window_s=T, steps=window_steps,
             tracked=drv.tracked,
             counters={k: c1[k] - c0[k] for k in c0}, trace=trace,
@@ -391,8 +381,8 @@ def run(args, rehearse: bool, fault) -> int:
     gc.collect()
     t_ref = time.perf_counter()
     with jax.default_device(devices[0]):
-        weights = jax.jit(reference.make_weights, static_argnums=0)(dims, key)
-        got = check.compare(weights, dims, sample, args.control)
+        weights = jax.jit(family.make_weights, static_argnums=0)(dims, key)
+        got = check.compare(family, weights, dims, sample, args.control)
     del weights
     checks = check.judged(got, cell["check"], bool(args.control))
     checks["compared_tokens"] = {"value": got["compared_tokens"], "limit": 1}

@@ -32,16 +32,23 @@ def last_json(out: str):
 
 def rehearse_added_cell(tmp_path, workload, cell, devices=1, traffic=None,
                         seed=2 ** 33 + 7, args=("--seconds", "3",
-                                                "--trace", "1")):
+                                                "--trace", "1"),
+                        config=None, files=None):
     """One run of ``workload`` (its entry, cell file and, where new, its
-    traffic file added to a copy of the benchmark) in a fresh process on
-    ``devices`` virtual CPU devices; returns the result line and stderr."""
+    traffic file added to a copy of the benchmark; where new, its
+    configuration's entry ``config``; and ``files``, text by path under
+    the copy) in a fresh process on ``devices`` virtual CPU devices;
+    returns the result line and stderr."""
     shutil.copytree(os.path.join(_paths.ROOT, "chipbench"),
                     tmp_path / "chipbench",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
     spec = json.load(open(os.path.join(_paths.ROOT, "BENCHMARK.json")))
     spec["workloads"].append(workload)
+    if config is not None:
+        spec["configs"].append(config)
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    for path, text in (files or {}).items():
+        (tmp_path / path).write_text(text)
     (tmp_path / "chipbench" / "cells" / (workload["name"] + ".json")
      ).write_text(json.dumps(cell))
     if traffic is not None:

@@ -18,11 +18,12 @@ import jax
 import jax.numpy as jnp
 
 from chipbench import check, reference
+from chipbench.families import dense
 
 CELL = os.path.join(_paths.ROOT, "chipbench", "cells", "internlm2-chat.json")
-DIMS = reference.Dims(n_layers=8, d_model=896, n_heads=14, n_kv_heads=2,
-                      head_dim=64, d_ff=4864, vocab=32768, qkv_bias=True,
-                      tied=True, rope_theta=1e6, eps=1e-5)
+DIMS = dense.Dims(n_layers=8, d_model=896, n_heads=14, n_kv_heads=2,
+                  head_dim=64, d_ff=4864, vocab=32768, qkv_bias=True,
+                  tied=True, rope_theta=1e6, eps=1e-5)
 PAD = 256
 
 
@@ -43,22 +44,22 @@ def greedy(w, prompt, n):
     for _ in range(n):
         toks = np.zeros(PAD, np.int32)
         toks[:len(seq)] = seq
-        h = reference.hidden(w, jnp.asarray(toks), DIMS)
-        row = reference.logits(w, h[len(seq) - 1:len(seq)], DIMS)
+        h = dense.hidden(w, jnp.asarray(toks), DIMS)
+        row = dense.logits(w, h[len(seq) - 1:len(seq)], DIMS)
         seq.append(int(np.argmax(np.asarray(row)[0])))
     return seq[len(prompt):]
 
 
 def test_float8_control_fails_and_float32_passes():
     limits = json.load(open(CELL))["check"]
-    w = jax.jit(reference.make_weights, static_argnums=0)(
+    w = jax.jit(dense.make_weights, static_argnums=0)(
         DIMS, reference.param_key(2 ** 40 + 3))
     rng = np.random.default_rng(1)
     sample = []
     for n in (150, 60):
         prompt = rng.integers(1, DIMS.vocab, n).tolist()
         sample.append(_Served(prompt, greedy(w, prompt, 24)))
-    got = check.compare(w, DIMS, sample, quant="fp8")
+    got = check.compare(dense, w, DIMS, sample, quant="fp8")
     assert got["compared_tokens"] == 48
     sound = check.judged(got, limits, control=False)
     ctl = check.judged(got, limits, control=True)

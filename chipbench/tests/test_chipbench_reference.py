@@ -11,12 +11,13 @@ import jax
 import jax.numpy as jnp
 
 from chipbench import reference
+from chipbench.families import dense
 from repro.configs import get_config
 from repro.models import build_model
 
 
 def dims_of(cfg):
-    return reference.Dims(
+    return dense.Dims(
         n_layers=cfg.n_layers, d_model=cfg.d_model, n_heads=cfg.n_heads,
         n_kv_heads=cfg.n_kv_heads, head_dim=cfg.hd, d_ff=cfg.d_ff,
         vocab=cfg.vocab, vocab_pad=cfg.vocab_pad_multiple,
@@ -31,7 +32,7 @@ def test_reference_matches_the_model(arch):
     key = reference.param_key(2 ** 35 + 11)
     model = build_model(cfg)
     params = model.init(key)
-    w = reference.make_weights(dm, key)
+    w = dense.make_weights(dm, key)
     flat_p = jax.tree_util.tree_leaves_with_path(params)
     flat_w = dict(jax.tree_util.tree_leaves_with_path(w))
     assert len(flat_p) == len(flat_w)
@@ -45,8 +46,8 @@ def test_reference_matches_the_model(arch):
     toks = np.random.default_rng(0).integers(1, cfg.vocab, 37).astype(np.int32)
     for n in (9, 37):
         want, _ = model.prefill(params, {"tokens": jnp.asarray(toks[None, :n])})
-        h = reference.hidden(w, jnp.asarray(toks[:n]), dm)
-        got = reference.logits(w, h[n - 1:n], dm)
+        h = dense.hidden(w, jnp.asarray(toks[:n]), dm)
+        got = dense.logits(w, h[n - 1:n], dm)
         np.testing.assert_allclose(np.asarray(got[0]),
                                    np.asarray(want[0, :cfg.vocab]),
                                    rtol=2e-4, atol=2e-5)
@@ -55,14 +56,14 @@ def test_reference_matches_the_model(arch):
 def test_gaps_are_zero_for_the_reference_greedy_tokens():
     cfg = get_config("qwen2-0.5b").reduced()
     dm = dims_of(cfg)
-    w = reference.make_weights(dm, reference.param_key(3))
+    w = dense.make_weights(dm, reference.param_key(3))
     prompt = list(range(1, 20))
     served = []
     for _ in range(5):
-        h = reference.hidden(w, jnp.asarray(prompt + served, jnp.int32), dm)
-        served.append(int(np.argmax(reference.logits(w, h[-1:], dm)[0])))
-    g = reference.gaps(w, dm, prompt, served, quant="fp8")
+        h = dense.hidden(w, jnp.asarray(prompt + served, jnp.int32), dm)
+        served.append(int(np.argmax(dense.logits(w, h[-1:], dm)[0])))
+    g = dense.gaps(w, dm, prompt, served, quant="fp8")
     assert g["gap_sd"].shape == (5,) and np.all(g["gap_sd"] < 1e-4)
     assert np.all(g["control_gap_sd"] >= 0)
     wrong = [(served[0] + 1) % cfg.vocab] + served[1:]
-    assert reference.gaps(w, dm, prompt, wrong)["gap_sd"][0] > 0
+    assert dense.gaps(w, dm, prompt, wrong)["gap_sd"][0] > 0

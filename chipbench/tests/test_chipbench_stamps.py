@@ -24,8 +24,8 @@ def _tracked(arrival_s, **stamps):
 
 
 def _reading(tracked, window_s=10.0):
-    return Reading(dims=None, peaks={}, max_batch=1, window_s=window_s,
-                   steps=[], tracked=tracked, counters={})
+    return Reading(dims=None, family=None, peaks={}, max_batch=1,
+                   window_s=window_s, steps=[], tracked=tracked, counters={})
 
 
 def test_stamp_readers_known_answers():

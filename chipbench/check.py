@@ -31,8 +31,9 @@ import numpy as np
 def pick_sample(tracked: List, n: int, chunk: int, seed: int) -> List:
     """``n`` requests that were served at least one token, drawn from the
     seed: the one with the most served tokens, then one of each path the
-    cell took (bucketed prefill, chunked prefill, shared-prefix suffix),
-    then the rest at random."""
+    cell took (a KV cache migrated by a reclaim and decoded on, bucketed
+    prefill, chunked prefill, shared-prefix suffix), then the rest at
+    random."""
     served = [t for t in tracked if t.sreq is not None and t.sreq.generated]
     if not served:
         return []
@@ -40,7 +41,8 @@ def pick_sample(tracked: List, n: int, chunk: int, seed: int) -> List:
     order = [served[i] for i in rng.permutation(len(served))]
     out = [max(order, key=lambda t: (len(t.sreq.generated),
                                      len(t.req.prompt)))]
-    paths = [lambda t: t.shared > 0,
+    paths = [lambda t: 0 < t.tokens_at_reclaim < len(t.sreq.generated),
+             lambda t: t.shared > 0,
              lambda t: t.shared == 0 and len(t.req.prompt) > chunk,
              lambda t: t.shared == 0 and len(t.req.prompt) <= chunk]
     for path in paths:

@@ -32,4 +32,20 @@ def state_unchanged(srv) -> None:
         model.decode_step = frozen
 
 
-FAULTS = {"token_altered": token_altered, "state_unchanged": state_unchanged}
+def handover_dropped(srv) -> None:
+    """The exchange between chips is left out: the requests a reclaim
+    takes off its replica are never handed to the replicas that survive
+    it."""
+    interrupt = srv.interrupt_instance
+
+    def dropped(instance_id, _interrupt=interrupt):
+        moved = _interrupt(instance_id)
+        ids = {id(r) for r in moved}
+        for p in srv.pipelines:
+            p.queue[:] = [r for r in p.queue if id(r) not in ids]
+        return moved
+    srv.interrupt_instance = dropped
+
+
+FAULTS = {"token_altered": token_altered, "state_unchanged": state_unchanged,
+          "handover_dropped": handover_dropped}

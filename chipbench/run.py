@@ -12,15 +12,18 @@ and ``chipbench/layer_metrics/<metric>.py``.
 
 Set-up (``setup_s``, process start to window open): params made on the
 chip from the seed, one ``GlobalServer`` pipeline per chip, a warm-up
-that reaches every program shape the cell's traffic uses, and the
-pre-roll, in which the traffic's arrivals before the window fill the
-server to its steady state. Then the window: ``--seconds`` of the open
-loop on the host clock, with nothing compiled inside it. After it, chat
-cells keep stepping until every request that arrived in the window has
-its first token. Then ``memory_peak_bytes`` is read, the program's state
-freed, and the served tokens of a sample of requests compared with the
-plain float32 reference of the configuration's model family
-(``chipbench/check.py``, ``chipbench/families/``).
+that reaches every program shape the cell's traffic uses (in a cell with
+a reclaim, also the export and attach of every block count the reclaim
+can move), and the pre-roll, in which the traffic's arrivals before the
+window fill the server to its steady state. Then the window:
+``--seconds`` of the open loop on the host clock, with nothing compiled
+inside it; a cell's ``reclaim_at_frac`` reclaims its ``reclaim_replica``
+that far into it. After it, chat cells keep stepping until every request
+that arrived in the window has its first token, and every request the
+reclaim moved a token since. Then ``memory_peak_bytes`` is read, the
+program's state freed, and the served tokens of a sample of requests
+compared with the plain float32 reference of the configuration's model
+family (``chipbench/check.py``, ``chipbench/families/``).
 
 With ``--trace 0`` the result carries the cell's end-to-end metrics;
 with ``--trace 1`` a few seconds in the middle of the window are traced
@@ -36,6 +39,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -93,13 +97,15 @@ class CompileCounter:
         self.n = 0
         self.secs = 0.0
         self.cache_hits = 0
+        self.names: List[str] = []
         monitoring.register_event_duration_secs_listener(self._on_duration)
         monitoring.register_event_listener(self._on_event)
 
-    def _on_duration(self, event: str, secs: float, **_) -> None:
+    def _on_duration(self, event: str, secs: float, **kw) -> None:
         if event == "/jax/core/compile/backend_compile_duration":
             self.n += 1
             self.secs += secs
+            self.names.append(str(kw.get("fun_name", "?")))
 
     def _on_event(self, event: str, **_) -> None:
         if event == "/jax/compilation_cache/cache_hits":
@@ -120,10 +126,15 @@ def enable_cache(jax) -> str:
     return where
 
 
-def counters(srv) -> Dict[str, float]:
-    keys = ("tokens_out", "prefills", "decode_steps", "prefix_shared_tokens")
-    return {k: float(sum(getattr(p.engine.stats, k) for p in srv.pipelines))
-            for k in keys}
+def counters(stats: List) -> Dict[str, float]:
+    """Every integer field of ``EngineStats``, summed over ``stats``."""
+    out: Dict[str, float] = {}
+    for st in stats:
+        for f in dataclasses.fields(st):
+            v = getattr(st, f.name)
+            if isinstance(v, int) and not isinstance(v, bool):
+                out[f.name] = out.get(f.name, 0.0) + v
+    return out
 
 
 def main(argv: Optional[List[str]] = None, rehearse: bool = False,
@@ -224,14 +235,6 @@ def run(args, rehearse: bool, fault) -> int:
     del params
     if fault is not None:
         fault(srv)
-    sp = bool(data["traffic"].get("shared_prefix"))
-    wrng = np.random.default_rng(args.seed ^ 0x3C3C)
-    warm_steps = serve.warm_up(
-        srv, serve.warmup_requests(geo, cfg.vocab, sp, wrng))
-    compiles_setup = compiles.n
-    log(f"warm-up: {warm_steps} steps, {compiles.n} compiles "
-        f"({compiles.secs:.1f} s), {compiles.cache_hits} cache hits")
-
     T = float(args.seconds)
     rate = args.rate if args.rate is not None else float(
         cell.get("rate_rps", 0.0))
@@ -246,20 +249,36 @@ def run(args, rehearse: bool, fault) -> int:
                         slots=geo["max_batch"] * replicas, vocab=cfg.vocab)
     log(f"traffic: {len(reqs)} requests, {sum(len(r.prompt) for r in reqs)}"
         f" prompt tokens, {sum(r.max_new for r in reqs)} output tokens")
-    t_open = time.perf_counter() + preroll
-    drv = serve.Driver(srv, reqs, t_open, geo["prefill_chunk"],
-                       trace_spans=bool(args.trace))
+    sp = bool(data["traffic"].get("shared_prefix"))
+    wrng = np.random.default_rng(args.seed ^ 0x3C3C)
+    warm_steps = serve.warm_up(
+        srv, serve.warmup_requests(geo, cfg.vocab, sp, wrng))
+    compiles_setup = compiles.n
+    log(f"warm-up: {warm_steps} steps, {compiles.n} compiles "
+        f"({compiles.secs:.1f} s), {compiles.cache_hits} cache hits")
     reclaim = None
     if cell.get("reclaim_at_frac") is not None:
         reclaim = (float(cell["reclaim_at_frac"]) * T,
                    f"chip{int(cell.get('reclaim_replica', 1))}")
+        nbs = serve.migration_block_counts(reqs, reclaim[0],
+                                           geo["block_size"], geo["max_len"])
+        n = compiles.n
+        attaches = serve.warm_migration(srv, reclaim[1], nbs, cfg.vocab,
+                                        wrng)
+        compiles_setup = compiles.n
+        log(f"migration warm-up: {len(nbs)} block counts "
+            f"({nbs[0] if nbs else 0}-{nbs[-1] if nbs else 0}) exported on "
+            f"{reclaim[1]}, {attaches} attaches, {compiles.n - n} compiles")
+    t_open = time.perf_counter() + preroll
+    drv = serve.Driver(srv, reqs, t_open, geo["prefill_chunk"],
+                       trace_spans=bool(args.trace))
     drv.run_until(0.0, None)                     # pre-roll
     setup_s = time.perf_counter() - T_START
     queued_open = sum(len(p.queue) for p in srv.pipelines)
     log(f"setup_s {setup_s:.2f} (pre-roll {preroll:.0f} s); compiles so far "
         f"{compiles.n} ({compiles.secs:.1f} s), cache hits "
         f"{compiles.cache_hits}")
-    c0, n_steps0 = counters(srv), len(drv.steps)
+    c0, n_steps0 = counters(drv.engine_stats()), len(drv.steps)
     compiles_open = compiles.n
 
     # -- the window ------------------------------------------------------------
@@ -287,13 +306,19 @@ def run(args, rehearse: bool, fault) -> int:
     if state["on"]:
         stop_trace(drv)
     for at, inst in drv.reclaimed:
-        log(f"reclaimed {inst} at {at:.2f} s of the window")
+        log(f"reclaimed {inst} at {at:.2f} s of the window: "
+            f"{len(drv.moved)} requests moved, "
+            f"{sum(1 for t in drv.moved if t.tokens_at_reclaim)} of them "
+            f"live with their KV")
     window_steps = drv.steps[n_steps0:]
-    c1 = counters(srv)
+    c1 = counters(drv.engine_stats())
     compiles_window = compiles.n - compiles_open
     chat = rate > 0
-    if chat:
+    unsent = sum(1 for t in drv.tracked[drv.next:] if t.req.arrival_s < T)
+    log(f"window arrivals unsent at its close: {unsent}")
+    if chat or drv.moved:
         drv.drain_first_tokens(T, float(cell.get("drain_max_s", 120.0)))
+    stranded = len(drv.stranded())
 
     # -- result numbers ----------------------------------------------------------
     arrived = [t for t in drv.tracked if 0.0 <= t.req.arrival_s < T]
@@ -330,6 +355,8 @@ def run(args, rehearse: bool, fault) -> int:
         f"{sum(len(p.queue) for p in srv.pipelines)}; steps in window "
         f"{len(window_steps)}; compiles: set-up {compiles_setup}, window "
         f"{compiles_window}")
+    if compiles_window:
+        log(f"compiled in the window: {compiles.names[compiles_open:]}")
 
     layer: Dict[str, float] = {}
     busy = window_ns = None
@@ -366,12 +393,17 @@ def run(args, rehearse: bool, fault) -> int:
         log(f"per-layer {name} = {v}")
 
     # -- memory, then free the program, then the reference ------------------------
-    peak = 0
-    for d in devices:
-        ms = d.memory_stats() or {}
-        peak = max(peak, int(ms.get("peak_bytes_in_use", 0)))
+    peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+             for d in devices]
+    peak = max(peaks)
+    log(f"memory peak by chip: {peaks} (limit "
+        f"{(dev0.memory_stats() or {}).get('bytes_limit')})")
     sample = check.pick_sample(drv.tracked, int(cell["check"]["sample"]),
                                geo["prefill_chunk"], args.seed)
+    log("sample: served tokens " + ", ".join(
+        f"{len(t.sreq.generated)}" + (f" (moved after {t.tokens_at_reclaim})"
+                                      if t.tokens_at_reclaim else "")
+        for t in sample))
     for t in sample:
         t.sreq = _Served(list(t.sreq.generated))
     for p in srv.pipelines:             # free the program's device state
@@ -387,9 +419,13 @@ def run(args, rehearse: bool, fault) -> int:
     checks = check.judged(got, cell["check"], bool(args.control))
     checks["compared_tokens"] = {"value": got["compared_tokens"], "limit": 1}
     checks["window_compiles"] = {"value": compiles_window, "limit": 0}
+    exact = ["window_compiles"]
+    if reclaim is not None:
+        checks["moved_without_token"] = {"value": stranded, "limit": 0}
+        exact.append("moved_without_token")
     correct = (all(checks[k]["value"] <= checks[k]["limit"]
-                   for k in check.LIMIT_KEYS)
-               and got["compared_tokens"] > 0 and compiles_window == 0)
+                   for k in [*check.LIMIT_KEYS, *exact])
+               and got["compared_tokens"] > 0)
     log(f"reference: {len(sample)} requests, {got['compared_tokens']} "
         f"served tokens compared in {time.perf_counter() - t_ref:.1f} s; "
         f"reference argmax agrees on {got['reference_argmax_agree']}")
@@ -421,7 +457,8 @@ def run(args, rehearse: bool, fault) -> int:
     for k in check.LIMIT_KEYS:
         log(f"{k} {checks[k]['value']!r} limit {checks[k]['limit']!r}")
     log(f"compared_tokens {got['compared_tokens']} limit >= 1")
-    log(f"window_compiles {compiles_window} limit 0")
+    for k in exact:
+        log(f"{k} {checks[k]['value']} limit {checks[k]['limit']}")
     print(json.dumps(result), flush=True)
     return 0
 

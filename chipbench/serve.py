@@ -32,6 +32,9 @@ class Tracked:
     left_queue_s: Optional[float] = None
     shared: int = 0                     # prompt tokens served from cache
     stamps: List[float] = dataclasses.field(default_factory=list)
+    # tokens it had when its replica was reclaimed under it (0: not live
+    # there, so no KV of its own to migrate)
+    tokens_at_reclaim: int = 0
 
 
 @dataclasses.dataclass
@@ -99,6 +102,69 @@ def warmup_requests(geo: Dict, vocab: int, shared_prefix: bool,
     return batches
 
 
+def migration_block_counts(reqs: List[Req], before_s: float, block: int,
+                           max_len: int) -> List[int]:
+    """Every block count a reclaim at ``before_s`` can export: a request
+    that arrived before it and is live, fully prefilled, after its k-th
+    token (k from 1 to max_new - 1) holds prompt + k - 1 tokens."""
+    out = set()
+    for r in reqs:
+        if r.arrival_s >= before_s:
+            continue
+        lo, hi = len(r.prompt), min(len(r.prompt) + r.max_new - 2,
+                                    max_len - 1)
+        if hi >= lo:
+            out.update(range(-(-lo // block), -(-hi // block) + 1))
+    return sorted(out)
+
+
+def warm_migration(srv, reclaimed: str, block_counts: List[int],
+                   vocab: int, rng: np.random.Generator) -> int:
+    """For each block count, a reclaim's steps through the server's own
+    path: a request served on the reclaimed replica until it is live with
+    that many blocks, its KV exported there as ``interrupt_instance``
+    exports it (``Engine.export_live_kv``) and published once for every
+    other replica (``GlobalServer._publish_kv``); each of those queues a
+    copy of the request, attaches its KV when it steps
+    (``GlobalServer._admit_kv_attached``) and decodes its last token.
+    Nothing is interrupted: the replicas and their instance ids stay as
+    built, and the pools and the store end empty of these requests.
+    Returns the attaches made."""
+    from repro.serving import ServeRequest
+    donor = next(p for p in srv.pipelines if reclaimed in p.instance_ids)
+    others = [p for p in srv.pipelines if p is not donor]
+    block = donor.engine.bm.block_size
+    n = 0
+    for nb in block_counts:
+        # the fewest prompt tokens that fill nb blocks: the step that
+        # prefills its last chunk may decode once more, which stays in them
+        prompt = rng.integers(1, vocab, (nb - 1) * block + 1).tolist()
+        req = ServeRequest(prompt=prompt, max_new_tokens=3)
+        donor.queue.append(req)
+        while not req.generated:
+            srv.step()
+        payload = donor.engine.export_live_kv()[req.rid]
+        donor.engine.evict_all()
+        if payload["k"].shape[1] != nb:
+            raise RuntimeError(f"the warm-up exported "
+                               f"{payload['k'].shape[1]} blocks for {nb}")
+        before = sum(p.engine.stats.kv_imports for p in others)
+        for p in others:
+            moved = ServeRequest(prompt=prompt,
+                                 max_new_tokens=len(req.generated) + 1,
+                                 generated=list(req.generated))
+            srv._publish_kv(srv._kv_key(moved), payload)
+            p.queue.append(moved)
+        while srv.pending():
+            srv.step()
+        got = sum(p.engine.stats.kv_imports for p in others) - before
+        if got != len(others):
+            raise RuntimeError(f"{got} of {len(others)} replicas attached "
+                               f"{nb} blocks in the warm-up")
+        n += got
+    return n
+
+
 def warm_up(srv, batches: List[List]) -> int:
     """Serve each batch to completion on every pipeline (queued on each
     directly, past the router); returns the steps it took."""
@@ -132,6 +198,8 @@ class Driver:
         self.spans = trace_spans
         self.tracing = False
         self.reclaimed: List[Tuple[float, str]] = []
+        self.retired: List = []           # EngineStats of replaced engines
+        self.moved: List[Tracked] = []    # requests a reclaim moved
         if trace_spans:
             self._wrap_engines()
 
@@ -161,12 +229,15 @@ class Driver:
                 setattr(eng, attr, wrapped)
             eng._cb_wrapped = True
 
-    def submit_due(self) -> None:
+    def submit_due(self, before: float = float("inf")) -> None:
+        """Submit every request due by now that arrives before
+        ``before``."""
         from repro.serving import ServeRequest
         now = self.now()
         with self._span("cb.submit"):
             while (self.next < len(self.tracked)
-                   and self.tracked[self.next].req.arrival_s <= now):
+                   and self.tracked[self.next].req.arrival_s <= now
+                   and self.tracked[self.next].req.arrival_s < before):
                 tr = self.tracked[self.next]
                 tr.sreq = ServeRequest(prompt=tr.req.prompt.tolist(),
                                        max_new_tokens=tr.req.max_new)
@@ -270,8 +341,27 @@ class Driver:
                 target.pieces.append((lo, hi - lo))
 
     def reclaim(self, instance: str) -> None:
-        self.srv.interrupt_instance(instance)
+        """Reclaim the replica ``instance`` through the server's own path;
+        the statistics of the engine it replaces are kept."""
+        self.retired.extend(p.engine.stats for p in self.srv.pipelines
+                            if p.alive and instance in p.instance_ids)
+        live = {id(t.sreq): t for t in self.inflight}
+        for r in self.srv.interrupt_instance(instance):
+            t = live.get(id(r))
+            if t is not None:
+                t.tokens_at_reclaim = len(r.generated)
+                self.moved.append(t)
         self.reclaimed.append((self.now(), instance))
+
+    def stranded(self) -> List[Tracked]:
+        """The requests a reclaim moved that have had no token since."""
+        return [t for t in self.moved
+                if len(t.stamps) <= t.tokens_at_reclaim]
+
+    def engine_stats(self) -> List:
+        """``EngineStats`` of every engine that served: the pipelines'
+        engines now, and those a reclaim replaced."""
+        return [p.engine.stats for p in self.srv.pipelines] + self.retired
 
     def run_until(self, t_end: float, reclaim: Optional[Tuple[float, str]],
                   trace_hook=None) -> None:
@@ -289,12 +379,15 @@ class Driver:
             self.step()
 
     def drain_first_tokens(self, t1: float, limit_s: float) -> None:
-        """No new arrivals: step until every request that arrived in the
-        window ``[0, t1)`` has its first token, or ``limit_s`` passes."""
+        """No new arrivals: submit what arrived in the window ``[0, t1)``
+        and is not yet submitted, then step until every request that
+        arrived in it has its first token, and every request a reclaim
+        moved a token since, or ``limit_s`` passes."""
         stop = self.now() + limit_s
         while self.now() < stop:
+            self.submit_due(before=t1)
             waiting = [t for t in self.tracked[:self.next]
                        if 0.0 <= t.req.arrival_s < t1 and not t.stamps]
-            if not waiting or not self.srv.pending():
+            if not (waiting or self.stranded()) or not self.srv.pending():
                 return
             self.step()

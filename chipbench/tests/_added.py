@@ -1,7 +1,7 @@
 """Cells that land as data alone, rehearsed in a copy of the benchmark
 with one more ``BENCHMARK.json`` entry and its files: the backlog cell
-of the paper's offline setting (``PERF.md``, Open questions) and a
-four-replica cell with a reclaim."""
+of the paper's offline setting and the four-replica cell with a reclaim
+(``PERF.md``, Open questions)."""
 
 import json
 import os
@@ -24,6 +24,25 @@ OFFLINE_CELL = {"rate_rps": 0.0, "preroll_s": 10.0, "trace_s": 8.0,
 OFFLINE = {"name": "internlm2-offline-decode", "config": "internlm2-1.8b",
            "traffic": "offline-decode", "chips": 1,
            "why": "a backlog at 64 rows: decode-bound throughput"}
+# four replicas behind the router, chip1 reclaimed a third of the way
+# into the window, its live KV migrated through the store (ShuntServe
+# paper section 5); the rate is the rehearsal's, which keeps every
+# reduced engine busy
+RECLAIM = {"name": "internlm2-reclaim-x4", "config": "internlm2-1.8b",
+           "traffic": "chat-azure", "chips": 4,
+           "why": "four replicas, chip1 reclaimed, KV migrated"}
+RECLAIM_CELL = {"rate_rps": 16.0, "preroll_s": 20.0, "drain_max_s": 120.0,
+                "trace_s": 8.0, "replicas": 4, "store": True,
+                "kv_migration": True, "reclaim_at_frac": 0.333,
+                "reclaim_replica": 1, "check": CHECK}
+RECLAIM_LAYER = "Migration (serving/server.py GlobalServer)"
+RECLAIM_METRICS = [
+    {"name": "migration_gap_p90_ms.reclaim", "unit": "ms", "better": "lower",
+     "source": "host_clock", "layer": RECLAIM_LAYER, "moves": "ttft_p90_ms",
+     "workloads": [RECLAIM["name"]]},
+    {"name": "kv_attach_pct.reclaim", "unit": "%", "better": "higher",
+     "source": "program_counter", "layer": RECLAIM_LAYER,
+     "moves": "ttft_p90_ms", "workloads": [RECLAIM["name"]]}]
 
 
 def last_json(out: str):
@@ -33,12 +52,15 @@ def last_json(out: str):
 def rehearse_added_cell(tmp_path, workload, cell, devices=1, traffic=None,
                         seed=2 ** 33 + 7, args=("--seconds", "3",
                                                 "--trace", "1"),
-                        config=None, files=None):
+                        config=None, files=None, per_layer=(),
+                        end_to_end=()):
     """One run of ``workload`` (its entry, cell file and, where new, its
     traffic file added to a copy of the benchmark; where new, its
-    configuration's entry ``config``; and ``files``, text by path under
-    the copy) in a fresh process on ``devices`` virtual CPU devices;
-    returns the result line and stderr."""
+    configuration's entry ``config``; its ``per_layer`` metric entries;
+    the names of the ``end_to_end`` metrics it reports beside
+    ``setup_s``; and ``files``, text by path under the copy) in a fresh
+    process on ``devices`` virtual CPU devices; returns the result line
+    and stderr."""
     shutil.copytree(os.path.join(_paths.ROOT, "chipbench"),
                     tmp_path / "chipbench",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
@@ -46,6 +68,10 @@ def rehearse_added_cell(tmp_path, workload, cell, devices=1, traffic=None,
     spec["workloads"].append(workload)
     if config is not None:
         spec["configs"].append(config)
+    spec["per_layer"] += list(per_layer)
+    for m in spec["end_to_end"]:
+        if m["name"] in end_to_end:
+            m["workloads"].append(workload["name"])
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
     for path, text in (files or {}).items():
         (tmp_path / path).write_text(text)

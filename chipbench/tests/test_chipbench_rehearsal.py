@@ -1,6 +1,8 @@
 """One cell end to end at the reduced configuration on the CPU (a
-rehearsal path the chip path never takes), and the runs that must fail:
-no TPU, and a checkout that holds only the benchmark."""
+rehearsal path the chip path never takes), the engine counters the
+harness reads, and the runs that must fail: no TPU, and a checkout that
+holds only the benchmark. (The four-chip reclaim cell's rehearsals are
+``test_chipbench_reclaim.py``.)"""
 
 import json
 import os
@@ -9,12 +11,13 @@ import subprocess
 import sys
 
 import _paths  # noqa: F401
-from _added import (CHECK, OFFLINE, OFFLINE_CELL, OFFLINE_DECODE, last_json,
+from _added import (OFFLINE, OFFLINE_CELL, OFFLINE_DECODE, last_json,
                     rehearse_added_cell)
 
 import jax
+import numpy as np
 
-from chipbench import run
+from chipbench import reference, run, serve
 
 
 def test_chat_cell_rehearsal_is_correct_and_names_the_cpu(capsys):
@@ -72,24 +75,28 @@ def test_offline_cell_rehearsal_is_correct(tmp_path):
     assert "mfu_pct.chat" not in res["metrics"]
 
 
-X4_CELL = {"rate_rps": 4.0, "preroll_s": 1.0, "trace_s": 2.0, "replicas": 4,
-           "store": True, "kv_migration": True, "reclaim_at_frac": 0.333,
-           "reclaim_replica": 1, "drain_max_s": 60, "check": CHECK}
-
-
-def test_four_chip_reclaim_cell_lands_as_data(tmp_path):
-    """A four-replica cell with a reclaim is a BENCHMARK.json entry and a
-    cell file: rehearsed on four virtual CPU devices, no code added."""
-    res, err = rehearse_added_cell(
-        tmp_path, {"name": "internlm2-reclaim-x4", "config": "internlm2-1.8b",
-                   "traffic": "chat-azure", "chips": 4,
-                   "why": "four replicas, one reclaimed"},
-        cell=X4_CELL, devices=4)
-    assert res["device"]["count"] == 4
-    assert "reclaimed chip1" in err
-    # the served tokens match the reference across the reclaim; whether a
-    # migration compiles inside the window depends on where the reclaim
-    # falls (PERF.md, Open questions), so ``correct`` is not asserted
-    gap = res["checks"]["widest_gap_sd"]
-    assert gap["value"] <= gap["limit"], err[-3000:]
-    assert res["checks"]["compared_tokens"]["value"] > 0
+def test_counters_sum_every_engine_counter_as_before():
+    """``counters`` sums every integer field of ``EngineStats``; on the
+    chat cell's rehearsal server after its warm-up, the four it summed by
+    name before read what they read then (pinned from that code)."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    config = run.load_json(os.path.join(_paths.ROOT, "chipbench", "configs",
+                                        "internlm2-1.8b.json"))
+    cell = run.load_json(os.path.join(_paths.ROOT, "chipbench", "cells",
+                                      "internlm2-chat.json"))
+    cfg = get_config(config["registry_key"]).reduced()
+    geo = serve.geometry(config, True)
+    seed = 2 ** 34 + 5
+    params = build_model(cfg).init(reference.param_key(seed))
+    srv = serve.build_server(cfg, geo, [params], jax.devices()[:1], cell)
+    rng = np.random.default_rng(seed ^ 0x3C3C)
+    assert serve.warm_up(
+        srv, serve.warmup_requests(geo, cfg.vocab, False, rng)) == 9
+    got = run.counters([p.engine.stats for p in srv.pipelines])
+    assert {k: got[k] for k in ("tokens_out", "prefills", "decode_steps",
+                                "prefix_shared_tokens")} == {
+        "tokens_out": 26.0, "prefills": 13.0, "decode_steps": 5.0,
+        "prefix_shared_tokens": 0.0}
+    assert got["kv_imports"] == 0.0 and got["prefill_chunks"] > 0
+    assert set(got) == {f for f in vars(srv.pipelines[0].engine.stats)}

@@ -72,13 +72,15 @@ def open_arrivals(rng, rate: float, t0: float, t1: float,
 
 def scaled(mix: Dict, factor: float) -> Dict:
     """The mix with every length scaled by ``factor`` (CPU rehearsals at
-    a reduced geometry only)."""
-    def lengths(spec: Dict) -> Dict:
+    a reduced geometry only). Prompts keep the mix's own shortest length,
+    so the rehearsal, like the chip, sends none shorter than a KV block
+    (a shorter one can open like an indexed prompt by chance)."""
+    def lengths(spec: Dict, floor: int = 2) -> Dict:
         out = dict(spec)
         for k in ("mean", "min", "max"):
-            out[k] = max(2, int(spec[k] * factor))
+            out[k] = max(floor, int(spec[k] * factor))
         return out
-    out = dict(mix, input=lengths(mix["input"]),
+    out = dict(mix, input=lengths(mix["input"], mix["input"]["min"]),
                output=lengths(mix["output"]))
     if mix.get("shared_prefix"):
         sp = dict(mix["shared_prefix"], suffix=lengths(

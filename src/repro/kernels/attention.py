@@ -11,7 +11,10 @@ The KV history is either
   ``(B, max_blocks)`` read through scalar prefetch: the BlockSpec index
   map reads ``tbl[b, ik]``, so each grid step DMAs exactly the pool block
   backing virtual positions ``[ik*block, (ik+1)*block)`` of row ``b`` and
-  no gathered page view is ever materialized.
+  no gathered page view is ever materialized. The paged entry points also
+  take the model's stacked pool ``(L, n_blocks, block, nkv, d)`` with a
+  ``layer`` index, a scalar-prefetch operand too, and read that layer
+  where it lies: the layer loop never slices a layer's pool out.
 
 Four entry points are cases of that one body: ``flash_attention``
 (prefill, bases 0, optionally non-causal), ``chunk_attention`` /
@@ -158,17 +161,30 @@ def _attn_kernel(*refs, n_prefetch: int, scale: float, causal: bool,
             o_ref[ih] = (acc_scr[ih] / denom).astype(o_ref.dtype)
 
 
+def _stacked(cache_k: jax.Array, cache_v: jax.Array, layer):
+    """(stacked pool K, V, layer index as a (1,) int32 prefetch operand):
+    one layer's pool ``(n_blocks, …)`` becomes a stack of one (a free
+    reshape), read at layer 0."""
+    if layer is None:
+        cache_k, cache_v, layer = cache_k[None], cache_v[None], 0
+    return cache_k, cache_v, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 def _attend(q: jax.Array, k: jax.Array, v: jax.Array, bases,
             block_tbl: Optional[jax.Array], *, name: str, causal: bool,
             window: Optional[int], block_q: int, block_kv: int, probe: bool,
-            interpret: bool):
+            interpret: bool, layer=None):
     """q: (B,C,nh,d); k/v: contiguous (B,S,nkv,d) when ``block_tbl`` is
-    None, else a pool (n_blocks, block, nkv, d) walked through
-    ``block_tbl`` (B, max_blocks). Returns o, or (o, probe_max).
-    ``name`` names the kernel's custom call: the profiler trace and the
-    benchmark's roofline readers find the kernel by it."""
+    None, else a pool walked through ``block_tbl`` (B, max_blocks): the
+    stacked pool (L, n_blocks, block, nkv, d) read at ``layer``, or one
+    layer's pool (n_blocks, block, nkv, d) with ``layer`` None. Returns o,
+    or (o, probe_max). ``name`` names the kernel's custom call: the
+    profiler trace and the benchmark's roofline readers find the kernel
+    by it."""
     b, c, nh, d = q.shape
-    nkv = k.shape[2]
+    if block_tbl is not None:
+        k, v, layer = _stacked(k, v, layer)
+    nkv = k.shape[-2]
     assert nh % nkv == 0, (nh, nkv)
     group = nh // nkv
     # token axes are leading block dims: any size tiles, so a length that a
@@ -183,7 +199,7 @@ def _attend(q: jax.Array, k: jax.Array, v: jax.Array, bases,
             block_kv = s
         nk = s // block_kv
     else:
-        block_kv = k.shape[1]
+        block_kv = k.shape[2]
         nk = block_tbl.shape[1]
     nq = c // block_q
     bases = jnp.asarray(bases, jnp.int32)
@@ -202,17 +218,17 @@ def _attend(q: jax.Array, k: jax.Array, v: jax.Array, bases,
 
         def kv_map(ib, iq, ik, bases_ref):
             return (ib, kv_block(ib, iq, ik, bases_ref), 0, 0)
-
-        def q_map(ib, iq, ik, bases_ref):
-            return (ib, iq, 0, 0)
+        kv_spec = pl.BlockSpec((None, block_kv, nkv, d), kv_map)
     else:
-        prefetch = (block_tbl.astype(jnp.int32), bases)
+        prefetch = (block_tbl.astype(jnp.int32), layer, bases)
 
-        def kv_map(ib, iq, ik, tbl_ref, bases_ref):
-            return (tbl_ref[ib, kv_block(ib, iq, ik, bases_ref)], 0, 0, 0)
+        def kv_map(ib, iq, ik, tbl_ref, layer_ref, bases_ref):
+            return (layer_ref[0], tbl_ref[ib, kv_block(ib, iq, ik, bases_ref)],
+                    0, 0, 0)
+        kv_spec = pl.BlockSpec((None, None, block_kv, nkv, d), kv_map)
 
-        def q_map(ib, iq, ik, tbl_ref, bases_ref):
-            return (ib, iq, 0, 0)
+    def q_map(ib, iq, ik, *_):
+        return (ib, iq, 0, 0)
 
     def o_map(ib, iq, ik, *_):
         return (ib, 0, iq, 0)
@@ -228,7 +244,6 @@ def _attend(q: jax.Array, k: jax.Array, v: jax.Array, bases,
     if probe:
         out_shape.append(jax.ShapeDtypeStruct((b, 1, LANES), jnp.float32))
         out_specs.append(pl.BlockSpec((None, 1, LANES), probe_map))
-    kv_spec = pl.BlockSpec((None, block_kv, nkv, d), kv_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(b, nq, nk),
@@ -282,15 +297,17 @@ def chunk_attention_paged(q: jax.Array, cache_k: jax.Array,
                           cache_v: jax.Array, block_tbl: jax.Array,
                           bases, *, window: Optional[int] = None,
                           block_q: int = 128, probe: bool = False,
-                          interpret: bool = False):
-    """q: (B,C,nh,d); cache_k/v: (n_blocks, block, nkv, d) pool with the
-    chunk already written; block_tbl: (B, max_blocks) int32 pool-block id
-    per virtual block (0 = trash block, masked); bases scalar or (B,).
-    Returns o, or (o, probe_max) when ``probe`` is armed."""
+                          interpret: bool = False, layer=None):
+    """q: (B,C,nh,d); cache_k/v: the stacked pool (L, n_blocks, block,
+    nkv, d) read at ``layer``, or one layer's pool (n_blocks, block, nkv,
+    d) with ``layer`` None, the chunk already written; block_tbl: (B,
+    max_blocks) int32 pool-block id per virtual block (0 = trash block,
+    masked); bases scalar or (B,). Returns o, or (o, probe_max) when
+    ``probe`` is armed."""
     return _attend(q, cache_k, cache_v, bases, block_tbl,
                    name="chunk_attention_paged", causal=True,
                    window=window, block_q=block_q, block_kv=0, probe=probe,
-                   interpret=interpret)
+                   interpret=interpret, layer=layer)
 
 
 def decode_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
@@ -333,7 +350,8 @@ def _head_slabs(buf, slot, nkv: int, span: int):
     return heads
 
 
-def _decode_paged_kernel(len_ref, tbl_ref, q_ref, k_hbm, v_hbm, *refs,
+def _decode_paged_kernel(len_ref, tbl_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                         *refs,
                          scale: float, window: Optional[int], nkv: int,
                          block: int, pages: int, probe: bool):
     if probe:
@@ -341,6 +359,7 @@ def _decode_paged_kernel(len_ref, tbl_ref, q_ref, k_hbm, v_hbm, *refs,
     else:
         (o_ref, kbuf, vbuf, sem, m_scr, l_scr, acc_scr), p_ref = refs, None
     b = pl.program_id(0)
+    layer = layer_ref[0]
     length = len_ref[b]                       # 0: a dead or pending row
     pos = length - 1
     first = (jnp.maximum(pos - window + 1, 0) // block
@@ -364,7 +383,8 @@ def _decode_paged_kernel(len_ref, tbl_ref, q_ref, k_hbm, v_hbm, *refs,
             blk = tbl_ref[b, first + i * pages + j] if start else 0
             at = pl.ds(pl.multiple_of(j * page_rows, page_rows), page_rows)
             for hbm, buf in ((k_hbm, kbuf), (v_hbm, vbuf)):
-                cp = pltpu.make_async_copy(hbm.at[blk], buf.at[slot, at],
+                cp = pltpu.make_async_copy(hbm.at[layer, blk],
+                                           buf.at[slot, at],
                                            sem.at[slot])
                 if start:
                     cp.start()
@@ -441,15 +461,17 @@ def _decode_paged_kernel(len_ref, tbl_ref, q_ref, k_hbm, v_hbm, *refs,
 def decode_attention_paged(q: jax.Array, cache_k: jax.Array,
                            cache_v: jax.Array, block_tbl: jax.Array,
                            pos, *, window: Optional[int] = None,
-                           probe: bool = False, interpret: bool = False):
-    """q: (B,1,nh,d); cache_k/v: (n_blocks, block, nkv, d) pool;
-    block_tbl: (B, max_blocks); pos scalar or (B,) — the position of the
-    current (already written) token per sequence. In the page loop a row
-    whose table starts at the trash block 0 is dead or pending: it reads
-    nothing and its output is 0. Returns o, or (o, probe_max) when
-    ``probe`` is armed."""
+                           probe: bool = False, interpret: bool = False,
+                           layer=None):
+    """q: (B,1,nh,d); cache_k/v: the stacked pool (L, n_blocks, block,
+    nkv, d) read at ``layer``, or one layer's pool (n_blocks, block, nkv,
+    d) with ``layer`` None; block_tbl: (B, max_blocks); pos scalar or (B,)
+    — the position of the current (already written) token per sequence.
+    In the page loop a row whose table starts at the trash block 0 is dead
+    or pending: it reads nothing and its output is 0. Returns o, or (o,
+    probe_max) when ``probe`` is armed."""
     b, _, nh, d = q.shape
-    _, block, nkv, _ = cache_k.shape
+    block, nkv = cache_k.shape[-3:-1]
     assert nh % nkv == 0, (nh, nkv)
     dt = jnp.dtype(cache_k.dtype)
     if d != LANES or not (dt == jnp.float32
@@ -462,7 +484,8 @@ def decode_attention_paged(q: jax.Array, cache_k: jax.Array,
         return _attend(q, cache_k, cache_v, pos, block_tbl,
                        name="decode_attention_paged", causal=True,
                        window=window, block_q=1, block_kv=0, probe=probe,
-                       interpret=interpret)
+                       interpret=interpret, layer=layer)
+    cache_k, cache_v, layer = _stacked(cache_k, cache_v, layer)
     group = nh // nkv
     tbl = block_tbl.astype(jnp.int32)
     pages = _decode_pages(block, nkv, d, cache_k.dtype, tbl.shape[1])
@@ -480,7 +503,7 @@ def decode_attention_paged(q: jax.Array, cache_k: jax.Array,
                                       lambda i, *_: (i, 0, 0)))
     span = pages * block
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b,),
         in_specs=[row_spec, pl.BlockSpec(memory_space=pltpu.HBM),
                   pl.BlockSpec(memory_space=pltpu.HBM)],
@@ -503,7 +526,7 @@ def decode_attention_paged(q: jax.Array, cache_k: jax.Array,
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name="decode_attention_paged",
-    )(lengths, tbl, qg, *(c.reshape(c.shape[0], block * nkv, d)
-                          for c in (cache_k, cache_v)))
+    )(lengths, tbl, layer, qg,
+      *(c.reshape(c.shape[:2] + (block * nkv, d)) for c in (cache_k, cache_v)))
     o = out[0].reshape(b, 1, nh, d)
     return (o, out[1]) if probe else o

@@ -75,12 +75,12 @@ def _decode_jit(q, cache_k, cache_v, pos, *, window, block_kv):
 @functools.partial(jax.jit, static_argnames=("window", "probe"))
 def decode_attention_paged(q, cache_k, cache_v, block_tbl, pos, *,
                            window: Optional[int] = None,
-                           probe: bool = False):
+                           probe: bool = False, layer=None):
     """Block-pool decode kernel; matches
     models.attention.decode_attention_paged's signature."""
     out = _attn.decode_attention_paged(q, cache_k, cache_v, block_tbl, pos,
                                        window=window, probe=probe,
-                                       interpret=_interpret())
+                                       interpret=_interpret(), layer=layer)
     return _check_probe(out, probe)
 
 
@@ -99,14 +99,15 @@ def chunk_attention(q, cache_k, cache_v, bases, *,
 @functools.partial(jax.jit, static_argnames=("window", "block_q", "probe"))
 def chunk_attention_paged(q, cache_k, cache_v, block_tbl, bases, *,
                           window: Optional[int] = None, block_q: int = 128,
-                          probe: bool = False):
+                          probe: bool = False, layer=None):
     """Flash chunk kernel against the block pool, walking the block table
     via scalar prefetch — no gathered page view is materialized. Covers
     the engine chunk path (scalar base) and the prefix-share suffix path
     (per-row bases)."""
     out = _attn.chunk_attention_paged(q, cache_k, cache_v, block_tbl, bases,
                                       window=window, block_q=block_q,
-                                      probe=probe, interpret=_interpret())
+                                      probe=probe, interpret=_interpret(),
+                                      layer=layer)
     return _check_probe(out, probe)
 
 

@@ -187,22 +187,24 @@ def chunk_attention(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
 # ---------------------------------------------------------------------------
 # Paged (block-table) KV cache — reference path
 #
-# The pool holds ``n_blocks`` fixed-size token blocks per layer:
-# ``cache_k/v: (n_blocks, block, nkv, d)`` (a per-layer slice of the stacked
-# ``(L, n_blocks, block, nkv, d)`` engine pool). ``block_tbl: (B, max_blocks)``
-# maps slot-virtual position t to pool block ``block_tbl[b, t // block]`` at
-# offset ``t % block``; unallocated entries point at the reserved trash block
-# 0, whose contents position masking keeps invisible. These are the pure-jnp
-# oracles for the Pallas paged kernels in ``repro.kernels.attention``.
+# The pool holds ``n_blocks`` fixed-size token blocks per layer: the stacked
+# engine pool ``cache_k/v: (L, n_blocks, block, nkv, d)`` read at ``layer``,
+# or, with ``layer`` None, one layer's pool ``(n_blocks, block, nkv, d)``.
+# ``block_tbl: (B, max_blocks)`` maps slot-virtual position t to pool block
+# ``block_tbl[b, t // block]`` at offset ``t % block``; unallocated entries
+# point at the reserved trash block 0, whose contents position masking keeps
+# invisible. These are the pure-jnp oracles for the Pallas paged kernels in
+# ``repro.kernels.attention``.
 # ---------------------------------------------------------------------------
 def _gather_pages(cache_k: jax.Array, cache_v: jax.Array,
-                  block_tbl: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Materialize each row's virtual KV view: (B, max_blocks*block, nkv, d)."""
+                  block_tbl: jax.Array, layer=None
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """Materialize each row's virtual KV view of pool layer ``layer``:
+    (B, max_blocks*block, nkv, d), one gather per pool."""
     b, mb = block_tbl.shape
-    blk = cache_k.shape[1]
-    pk = jnp.take(cache_k, block_tbl, axis=0)     # (B, MB, blk, nkv, d)
-    pv = jnp.take(cache_v, block_tbl, axis=0)
-    shape = (b, mb * blk) + cache_k.shape[2:]
+    at = block_tbl if layer is None else (layer, block_tbl)
+    pk, pv = cache_k[at], cache_v[at]             # (B, MB, blk, nkv, d)
+    shape = (b, mb * cache_k.shape[-3]) + cache_k.shape[-2:]
     return pk.reshape(shape), pv.reshape(shape)
 
 
@@ -225,13 +227,14 @@ def _poison_probe(pk: jax.Array, pv: jax.Array, readable: jax.Array) -> None:
 def decode_attention_paged(q: jax.Array, cache_k: jax.Array,
                            cache_v: jax.Array, block_tbl: jax.Array,
                            pos: jax.Array, window: Optional[int] = None,
-                           probe: bool = False) -> jax.Array:
-    """Block-table ``decode_attention``. q: (B,1,nh,d); cache_k/v:
-    (n_blocks, block, nkv, d); pos scalar or (B,), position of the current
-    (already written) token."""
+                           probe: bool = False, layer=None) -> jax.Array:
+    """Block-table ``decode_attention``. q: (B,1,nh,d); cache_k/v: the
+    stacked pool read at ``layer``, or one layer's pool with ``layer``
+    None; pos scalar or (B,), position of the current (already written)
+    token."""
     if pos.ndim == 0:
         pos = jnp.broadcast_to(pos, (q.shape[0],))
-    pk, pv = _gather_pages(cache_k, cache_v, block_tbl)
+    pk, pv = _gather_pages(cache_k, cache_v, block_tbl, layer)
     kpos = jnp.arange(pk.shape[1])
     valid = kpos[None, :] <= pos[:, None]
     if window is not None:
@@ -247,10 +250,10 @@ def decode_attention_paged(q: jax.Array, cache_k: jax.Array,
 def chunk_attention_paged(q: jax.Array, cache_k: jax.Array,
                           cache_v: jax.Array, block_tbl: jax.Array,
                           q_pos: jax.Array, window: Optional[int] = None,
-                          probe: bool = False) -> jax.Array:
+                          probe: bool = False, layer=None) -> jax.Array:
     """Block-table ``chunk_attention``: (B,C) queries at absolute positions
-    ``q_pos`` against each row's gathered pages."""
-    pk, pv = _gather_pages(cache_k, cache_v, block_tbl)
+    ``q_pos`` against each row's gathered pages of pool layer ``layer``."""
+    pk, pv = _gather_pages(cache_k, cache_v, block_tbl, layer)
     kpos = jnp.arange(pk.shape[1])
     valid = kpos[None, None, :] <= q_pos[:, :, None]        # (B, C, S)
     if window is not None:
@@ -263,36 +266,46 @@ def chunk_attention_paged(q: jax.Array, cache_k: jax.Array,
     return sdpa(q, pk, pv, mask)
 
 
+def _write_rows(cache_k: jax.Array, cache_v: jax.Array, k: jax.Array,
+                v: jax.Array, layer, dest, off) -> Tuple[jax.Array, jax.Array]:
+    """One scatter per pool of K/V rows to blocks ``dest`` at offsets
+    ``off``, in pool layer ``layer`` (None: one layer's pool). A stacked
+    pool carried through the layer loop is updated in place: nothing but
+    the written rows moves."""
+    at = (dest, off) if layer is None else (layer, dest, off)
+    return (cache_k.at[at].set(k.astype(cache_k.dtype)),
+            cache_v.at[at].set(v.astype(cache_v.dtype)))
+
+
 def cache_write_token_paged(cache_k: jax.Array, cache_v: jax.Array,
                             k: jax.Array, v: jax.Array, pos: jax.Array,
-                            block_tbl: jax.Array
+                            block_tbl: jax.Array, layer=None
                             ) -> Tuple[jax.Array, jax.Array]:
     """Write one token's K/V (B,1,nkv,d) at per-row virtual position ``pos``
-    through the block table. Dead/frozen rows whose table entry is the trash
-    block write garbage there (never read)."""
-    blk = cache_k.shape[1]
-    k = k.astype(cache_k.dtype)
-    v = v.astype(cache_v.dtype)
+    through the block table, into pool layer ``layer`` of a stacked pool
+    (None: one layer's pool). Dead/frozen rows whose table entry is the
+    trash block write garbage there (never read)."""
+    blk = cache_k.shape[-3]
     if pos.ndim == 0:
         pos = jnp.broadcast_to(pos, (k.shape[0],))
     dest = jnp.take_along_axis(block_tbl, (pos // blk)[:, None],
                                axis=1)[:, 0]                 # (B,)
-    off = pos % blk
-    return cache_k.at[dest, off].set(k[:, 0]), cache_v.at[dest, off].set(
-        v[:, 0])
+    return _write_rows(cache_k, cache_v, k[:, 0], v[:, 0], layer, dest,
+                       pos % blk)
 
 
 def cache_write_chunk_paged(cache_k: jax.Array, cache_v: jax.Array,
                             k: jax.Array, v: jax.Array, base: jax.Array,
                             block_tbl: jax.Array,
-                            lens: Optional[jax.Array] = None
+                            lens: Optional[jax.Array] = None, layer=None
                             ) -> Tuple[jax.Array, jax.Array]:
     """Write a C-token chunk's K/V (B,C,nkv,d) at virtual positions
-    [base, base+C) through the block table. ``base`` may be per-row (B,) —
+    [base, base+C) through the block table, into pool layer ``layer`` of a
+    stacked pool (None: one layer's pool). ``base`` may be per-row (B,) —
     the prefix-sharing suffix path, where each row starts at its own
     shared-prefix boundary — and ``lens`` (B,) masks each row's columns
     past its real length into the trash block (pad rows/columns)."""
-    blk = cache_k.shape[1]
+    blk = cache_k.shape[-3]
     ar = jnp.arange(k.shape[1])                              # (C,)
     if jnp.ndim(base) == 0:
         t = base + ar                                        # (C,)
@@ -305,8 +318,7 @@ def cache_write_chunk_paged(cache_k: jax.Array, cache_v: jax.Array,
     off = t % blk                                            # broadcasts
     if lens is not None:
         dest = jnp.where(ar[None, :] < lens[:, None], dest, 0)
-    return (cache_k.at[dest, off].set(k.astype(cache_k.dtype)),
-            cache_v.at[dest, off].set(v.astype(cache_v.dtype)))
+    return _write_rows(cache_k, cache_v, k, v, layer, dest, off)
 
 
 def cache_write_token(cache_k: jax.Array, cache_v: jax.Array, k: jax.Array,

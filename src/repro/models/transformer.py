@@ -229,23 +229,24 @@ class LM:
         return o, ck, cv, slot_new
 
     def _attn_decode_paged(self, p: Dict, x: jax.Array, pos, cache_k,
-                           cache_v, block_tbl):
-        """One-token attention against this layer's block pool: write the
-        token through the block table, attend over gathered pages."""
+                           cache_v, layer, block_tbl):
+        """One-token attention against layer ``layer`` of the stacked block
+        pool: write the token through the block table, attend over the
+        row's pages."""
         c = self.cfg
         positions = self._decode_positions(pos, x.shape[0])
         q, k, v = self._qkv(p, x, positions)
         ck, cv = attn.cache_write_token_paged(cache_k, cache_v, k, v, pos,
-                                              block_tbl)
+                                              block_tbl, layer=layer)
         if self.use_pallas:
             from repro.kernels import ops as kops
             o = kops.decode_attention_paged(q, ck, cv, block_tbl, pos,
                                             window=c.swa_window,
-                                            probe=self.kv_probe)
+                                            probe=self.kv_probe, layer=layer)
         else:
             o = attn.decode_attention_paged(q, ck, cv, block_tbl, pos,
                                             window=c.swa_window,
-                                            probe=self.kv_probe)
+                                            probe=self.kv_probe, layer=layer)
         o = o.reshape(x.shape[0], 1, c.n_heads * c.hd)
         o = o @ p["wo"]
         if "bo" in p:
@@ -309,25 +310,26 @@ class LM:
         h = self.norm(x, p["ln_mlp"])
         return x + self._mlp_or_moe(p, h), ck, cv, slot_new
 
-    def _dense_layer_decode_paged(self, p: Dict, x, pos, ck, cv, block_tbl):
+    def _dense_layer_decode_paged(self, p: Dict, x, pos, ck, cv, layer,
+                                  block_tbl):
         h = self.norm(x, p["ln_attn"])
-        a, ck, cv = self._attn_decode_paged(p["attn"], h, pos, ck, cv,
+        a, ck, cv = self._attn_decode_paged(p["attn"], h, pos, ck, cv, layer,
                                             block_tbl)
         x = x + a
         h = self.norm(x, p["ln_mlp"])
         return x + self._mlp_or_moe(p, h), ck, cv
 
     def _dense_layer_chunk(self, p: Dict, x, q_pos, ck, cv, base,
-                           block_tbl=None, lens=None):
+                           block_tbl=None, lens=None, layer=None):
         """Chunked-prefill layer body: C new tokens against a linear cache.
 
         Writes the chunk's K/V at [base, base+C) and attends every query
         against the whole cache under per-query position masking — the
         C-token generalization of ``_dense_layer_decode``. With
-        ``block_tbl`` the cache slice is a block pool and writes/reads go
-        through the table; per-row ``base``/``lens`` (the prefix-sharing
-        suffix path) route each row to its own boundary and mask pad
-        columns into the trash block.
+        ``block_tbl`` ``ck``/``cv`` are the stacked block pool, written and
+        read at ``layer`` through the table; per-row ``base``/``lens`` (the
+        prefix-sharing suffix path) route each row to its own boundary and
+        mask pad columns into the trash block.
         """
         c = self.cfg
         h = self.norm(x, p["ln_attn"])
@@ -340,16 +342,19 @@ class LM:
         # kernel takes the bases instead of the dense position grid)
         if block_tbl is not None:
             ck, cv = attn.cache_write_chunk_paged(ck, cv, k, v, base,
-                                                  block_tbl, lens=lens)
+                                                  block_tbl, lens=lens,
+                                                  layer=layer)
             if self.use_pallas:
                 from repro.kernels import ops as kops
                 o = kops.chunk_attention_paged(q, ck, cv, block_tbl, base,
                                                window=c.swa_window,
-                                               probe=self.kv_probe)
+                                               probe=self.kv_probe,
+                                               layer=layer)
             else:
                 o = attn.chunk_attention_paged(q, ck, cv, block_tbl, q_pos,
                                                window=c.swa_window,
-                                               probe=self.kv_probe)
+                                               probe=self.kv_probe,
+                                               layer=layer)
         else:
             assert lens is None, "column masking requires the paged path"
             ck = jax.lax.dynamic_update_slice_in_dim(
@@ -450,6 +455,22 @@ class LM:
             k, v, aux = ys
             return x, {"k": k, "v": v, "aux": jnp.sum(aux)}
         return x, {"aux": jnp.sum(ys)}
+
+    def _scan_pool(self, params: Dict, x: jax.Array, cache: Dict, layer_fn):
+        """Run ``layer_fn(p_l, h, pool_k, pool_v, l) -> (h, pool_k,
+        pool_v)`` over the layers with the stacked K/V pool in the loop's
+        carry, not in its xs/ys: each layer scatters only its new rows into
+        the carried (donated) pool, in place, and the kernels read layer
+        ``l`` where it lies, so no layer's pool is sliced out or stacked
+        back. Returns (x, pool_k, pool_v)."""
+        def body(carry, xs):
+            h, pk, pv = carry
+            p_l, layer = xs
+            return layer_fn(p_l, h, pk, pv, layer), None
+        layers = jnp.arange(self.cfg.n_layers, dtype=jnp.int32)
+        (x, pk, pv), _ = jax.lax.scan(body, (x, cache["k"], cache["v"]),
+                                      (params["layers"], layers))
+        return x, pk, pv
 
     def _run_hybrid_full(self, params: Dict, x: jax.Array, positions,
                          collect_kv: bool):
@@ -700,13 +721,20 @@ class LM:
         direct = block_tbl is not None
         tbl = block_tbl if direct else cache.get("block_tbl")
 
-        def body(h, xs):
-            p_l, ck, cv = xs
-            h, ck, cv = self._dense_layer_chunk(p_l, h, q_pos, ck, cv, base,
-                                                block_tbl=tbl, lens=lens)
-            return h, (ck, cv)
-        x, (ck, cv) = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
+        if tbl is None:
+            def body(h, xs):
+                p_l, ck, cv = xs
+                h, ck, cv = self._dense_layer_chunk(p_l, h, q_pos, ck, cv,
+                                                    base)
+                return h, (ck, cv)
+            x, (ck, cv) = jax.lax.scan(
+                body, x, (params["layers"], cache["k"], cache["v"]))
+        else:
+            x, ck, cv = self._scan_pool(
+                params, x, cache,
+                lambda p_l, h, pk, pv, layer: self._dense_layer_chunk(
+                    p_l, h, q_pos, pk, pv, base, block_tbl=tbl, lens=lens,
+                    layer=layer))
         new_cache = dict(cache)
         new_cache["k"], new_cache["v"] = ck, cv
         if not direct:
@@ -743,15 +771,11 @@ class LM:
         x = jnp.take(params["embed"]["tok"], tokens, axis=0)
         b, cl = tokens.shape
         q_pos = bases[:, None] + jnp.arange(cl)[None, :]
-
-        def body(h, xs):
-            p_l, ck, cv = xs
-            h, ck, cv = self._dense_layer_chunk(p_l, h, q_pos, ck, cv,
-                                                bases, block_tbl=block_tbl,
-                                                lens=lens)
-            return h, (ck, cv)
-        x, (ck, cv) = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
+        x, ck, cv = self._scan_pool(
+            params, x, cache,
+            lambda p_l, h, pk, pv, layer: self._dense_layer_chunk(
+                p_l, h, q_pos, pk, pv, bases, block_tbl=block_tbl, lens=lens,
+                layer=layer))
         new_cache = dict(cache)
         new_cache["k"], new_cache["v"] = ck, cv
         x = self.norm(x, params["final_norm"])
@@ -783,15 +807,10 @@ class LM:
                                                pos)
         elif "block_tbl" in cache:
             tbl = cache["block_tbl"]
-
-            def body(h, xs):
-                p_l, ck, cv = xs
-                h, ck, cv = self._dense_layer_decode_paged(
-                    p_l, h, pos, ck, cv, tbl)
-                return h, (ck, cv)
-            x, (ck, cv) = jax.lax.scan(
-                body, x, (params["layers"], cache["k"], cache["v"]))
-            new_cache["k"], new_cache["v"] = ck, cv
+            x, new_cache["k"], new_cache["v"] = self._scan_pool(
+                params, x, cache,
+                lambda p_l, h, pk, pv, layer: self._dense_layer_decode_paged(
+                    p_l, h, pos, pk, pv, layer, tbl))
         else:
             slot = cache.get("slot_pos")
 

@@ -104,6 +104,19 @@ def _pool(b, mb, block, nkv, d, dtype):
     return pk, pv, tbl
 
 
+def _three_layers(pk, pv, layer):
+    """A 3-layer stacked pool holding ``pk``/``pv`` at ``layer`` and
+    distinct random contents in the other layers (None: the pool as
+    given, one layer's)."""
+    if layer is None:
+        return pk, pv
+
+    def stack(p):
+        return jnp.asarray(RNG.randn(3, *p.shape), p.dtype).at[layer].set(p)
+    return stack(pk), stack(pv)
+
+
+@pytest.mark.parametrize("layer", [None, 0, 2])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize(
     "b,c,nh,nkv,d,window,vecbase",
@@ -114,22 +127,29 @@ def _pool(b, mb, block, nkv, d, dtype):
         (2, 64, 8, 2, 128, None, True),          # GQA, d=128, sub-tile
     ])
 def test_chunk_attention_paged_sweep(b, c, nh, nkv, d, window, vecbase,
-                                     dtype):
+                                     dtype, layer):
     """Flash chunk kernel walking the block pool via scalar-prefetched
-    block tables == jnp paged oracle (which gathers a page view)."""
+    block tables == jnp paged oracle (which gathers a page view) on one
+    layer's pool; with ``layer`` set, the kernel and the oracle read that
+    layer of a stacked pool in place and equal the oracle on it alone."""
     block, mb = 64, 4                            # virtual length 256
     pk, pv, tbl = _pool(b, mb, block, nkv, d, dtype)
     s_virt = block * mb
     q = jnp.asarray(RNG.randn(b, c, nh, d), dtype)
     bases = (jnp.asarray(RNG.randint(0, s_virt - c + 1, (b,)), jnp.int32)
              if vecbase else jnp.asarray(s_virt - c, jnp.int32))
-    out = chunk_attention_paged(q, pk, pv, tbl, bases, window=window,
-                                interpret=True)
+    sk, sv = _three_layers(pk, pv, layer)
+    out = chunk_attention_paged(q, sk, sv, tbl, bases, window=window,
+                                interpret=True, layer=layer)
     q_pos = (jnp.broadcast_to(bases, (b,))[:, None]
              + jnp.arange(c)[None]).astype(jnp.int32)
     ref = mattn.chunk_attention_paged(q, pk, pv, tbl, q_pos, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
+    np.testing.assert_array_equal(
+        np.asarray(mattn.chunk_attention_paged(q, sk, sv, tbl, q_pos,
+                                               window=window, layer=layer)),
+        np.asarray(ref))
 
 
 def test_chunk_paged_probe_flags_readable_poison():
@@ -177,6 +197,7 @@ def _close(out, ref, dtype):
                                np.asarray(ref, np.float32), **_tol(dtype))
 
 
+@pytest.mark.parametrize("layer", [None, 0, 2])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.float16])
 @pytest.mark.parametrize(
     "nh,nkv,d,window",
@@ -187,14 +208,16 @@ def _close(out, ref, dtype):
         (4, 4, 128, None),        # MHA
         (4, 2, 128, 520),         # SWA, window start past page 0
     ])
-def test_decode_attention_paged_sweep(nh, nkv, d, window, dtype,
+def test_decode_attention_paged_sweep(nh, nkv, d, window, dtype, layer,
                                       monkeypatch):
     """Paged decode == jnp paged oracle at per-row positions 0, block-1,
     block and one whose length passes one DMA step of pages without being
     a multiple of it, beside a dead row (table all trash block 0) whose
     output must only be finite. Head size 128 in float32 or bfloat16 runs
     the page loop; head size 64 and float16 run the block walk, and each
-    case asserts which path it took."""
+    case asserts which path it took. With ``layer`` set, the kernel and
+    the oracle read that layer of a stacked pool in place and equal the
+    oracle on that layer's pool alone."""
     walks, attend = [], ka._attend
 
     def block_walk(*a, **kw):
@@ -208,10 +231,15 @@ def test_decode_attention_paged_sweep(nh, nkv, d, window, dtype,
     long = pages * block + 37
     pos = jnp.asarray([0, block - 1, block, long, 300], jnp.int32)
     q = jnp.asarray(RNG.randn(5, 1, nh, d), dtype)
-    out = decode_attention_paged(q, pk, pv, tbl, pos, window=window,
-                                 interpret=True)
+    sk, sv = _three_layers(pk, pv, layer)
+    out = decode_attention_paged(q, sk, sv, tbl, pos, window=window,
+                                 interpret=True, layer=layer)
     ref = mattn.decode_attention_paged(q, pk, pv, tbl, pos, window=window)
     _close(out[:4], ref[:4], dtype)
+    np.testing.assert_array_equal(
+        np.asarray(mattn.decode_attention_paged(q, sk, sv, tbl, pos,
+                                                window=window, layer=layer)),
+        np.asarray(ref))
     assert np.isfinite(np.asarray(out[4], np.float32)).all()
     page_loop = d == 128 and dtype != jnp.float16
     assert bool(walks) != page_loop

@@ -203,6 +203,102 @@ def test_attention_paged_refs_match_contig():
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), rtol=1e-6)
 
 
+# -- the layer loop carries the stacked pool -----------------------------------
+
+def _layer_slice_run(model, params, cache, x, layer_fn):
+    """The paged layer loop with each layer's pool in the scan's xs/ys
+    (sliced out, written, stacked back), as it ran before the pool moved
+    into the carry: the reference the carried pool has to reproduce."""
+    def body(h, xs):
+        p_l, ck, cv = xs
+        h, ck, cv = layer_fn(p_l, h, ck, cv)
+        return h, (ck, cv)
+    x, (k, v) = jax.lax.scan(body, x, (params["layers"], cache["k"],
+                                       cache["v"]))
+    return model.norm(x, params["final_norm"]), k, v
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+@pytest.mark.parametrize("program", ["decode", "chunk", "suffix"])
+def test_carried_pool_writes_only_new_rows(program, use_pallas):
+    """One paged decode step, engine-direct chunk or prefix suffix on a
+    4-layer pool of distinct random contents: every layer's pool equals
+    the old one bitwise except at the rows written, ``(l, dest, off)``
+    for each written row in every layer (masked columns into the trash
+    block 0), those rows changed, and logits and pools match the
+    per-layer-slice loop the step ran before."""
+    cfg = get_config("internlm2-1.8b").reduced()
+    model = build_model(cfg, remat=False, attn_chunk=0,
+                        use_pallas=use_pallas)
+    params = model.init(jax.random.PRNGKey(0))
+    b, max_len, blk = 3, 64, 8
+    mb = max_len // blk
+    rng = np.random.RandomState(11)
+    cache = model.init_cache(b, max_len, vector_pos=True, kv_layout="paged",
+                             n_blocks=b * mb + 1, block_size=blk)
+    for key in ("k", "v"):
+        cache[key] = jnp.asarray(rng.randn(*cache[key].shape),
+                                 cache[key].dtype)
+    tbl = rng.permutation(np.arange(1, b * mb + 1)).reshape(b, mb)
+    cache["block_tbl"] = jnp.asarray(tbl, jnp.int32)
+    rows = np.arange(b)
+    written = np.zeros(cache["k"].shape[:3], bool)      # (L, blocks, blk)
+    emb = params["embed"]["tok"]
+    if program == "decode":
+        pos = np.array([3, 17, 40])
+        cache["pos"] = jnp.asarray(pos, jnp.int32)
+        tokens = jnp.asarray(rng.randint(0, cfg.vocab, (b, 1)), jnp.int32)
+        logits, new = model.decode_step(params, cache, tokens)
+        pos_j = cache["pos"]
+        h, ref_k, ref_v = _layer_slice_run(
+            model, params, cache, jnp.take(emb, tokens, axis=0),
+            lambda p_l, h, ck, cv: model._dense_layer_decode_paged(
+                p_l, h, pos_j, ck, cv, None, cache["block_tbl"]))
+        ref_logits = model.logits(params, h)
+        written[:, tbl[rows, pos // blk], pos % blk] = True
+    else:
+        c = 8
+        # a chunk row that finished writes nothing; a suffix row is real
+        lens = np.array([8, 5, 0 if program == "chunk" else 1])
+        bases = (np.array([16, 16, 16]) if program == "chunk"
+                 else np.array([16, 9, 30]))
+        tokens = jnp.asarray(rng.randint(0, cfg.vocab, (b, c)), jnp.int32)
+        lens_j = jnp.asarray(lens, jnp.int32)
+        last = jnp.asarray(np.maximum(lens - 1, 0), jnp.int32)
+        if program == "chunk":
+            base_j = jnp.asarray(16, jnp.int32)
+            logits, new = model.prefill_chunk(
+                params, cache, tokens, base_j, last_pos=last,
+                block_tbl=cache["block_tbl"], lens=lens_j)
+        else:
+            base_j = jnp.asarray(bases, jnp.int32)
+            logits, new = model.prefill_suffix(
+                params, cache, tokens, base_j, cache["block_tbl"], lens_j)
+        q_pos = jnp.asarray(bases[:, None] + np.arange(c)[None], jnp.int32)
+        h, ref_k, ref_v = _layer_slice_run(
+            model, params, cache, jnp.take(emb, tokens, axis=0),
+            lambda p_l, h, ck, cv: model._dense_layer_chunk(
+                p_l, h, q_pos, ck, cv, base_j,
+                block_tbl=cache["block_tbl"], lens=lens_j))
+        ref_logits = model.logits(params, h[rows, last][:, None])[:, 0]
+        t = bases[:, None] + np.arange(c)[None]
+        dest = np.where(np.arange(c)[None] < lens[:, None],
+                        tbl[rows[:, None], t // blk], 0)
+        written[:, dest, t % blk] = True
+    assert written[:, 1:].any() and not written.all()
+    for old, out, ref in ((cache["k"], new["k"], ref_k),
+                          (cache["v"], new["v"], ref_v)):
+        old, out = np.asarray(old), np.asarray(out)
+        np.testing.assert_array_equal(out[~written], old[~written])
+        live = written.copy()
+        live[:, 0] = False                    # trash rows: any value
+        assert (out[live] != old[live]).any(axis=(-2, -1)).all()
+        np.testing.assert_allclose(out[live], np.asarray(ref)[live],
+                                   rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits),
+                               rtol=1e-5, atol=1e-6)
+
+
 # -- fragmentation / backpressure ----------------------------------------------
 
 def test_admit_finish_churn_never_leaks_blocks(setup):

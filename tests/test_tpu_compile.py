@@ -11,8 +11,11 @@ roofline reader for it matches (``KERNELS`` in
 metric unnoticed.
 """
 
+import dataclasses
 import importlib
+import math
 import os
+import re
 import sys
 
 import jax
@@ -152,5 +155,93 @@ def test_flash_prefill_compiles(one_chip):
                         ((group, seq, nkv, d), jnp.bfloat16),
                         ((group, seq, nkv, d), jnp.bfloat16))
     assert "tpu_custom_call" in compiled.as_text()
+    assert _kernel_kinds(compiled) & set(_reader_kernels(
+        "prefill_attn_roofline"))
+
+
+# -- the whole paged step: the layer loop moves no layer's pool ---------------
+
+POOL_BLOCKS = 3600                  # the benchmark's pool, internlm2-1.8b
+POOL_BATCH, POOL_LEN = 64, 4096
+POOL_COPY_OPS = ("copy", "dynamic-slice", "dynamic-update-slice")
+
+
+def _pool_sized_moves(compiled, limit):
+    """Instructions of the optimised HLO that copy, slice or update-slice
+    an array of ``limit`` bytes or more: by opcode, or by the name of a
+    fusion (``bitcast_dynamic-update-slice_fusion.3``), as the profiler
+    trace names them."""
+    itemsize = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4}
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \(?(\w+)\[([\d,]*)\]\S* (\S+)\(",
+                     line)
+        if not m or m.group(2) not in itemsize:
+            continue
+        name, dtype, dims, op = m.groups()
+        size = itemsize[dtype] * math.prod(int(n) for n in dims.split(",")
+                                           if n)
+        kinds = {op} | set(name.split(".")[0].split("_"))
+        if size >= limit and kinds & set(POOL_COPY_OPS):
+            found.append((name, op, f"{dtype}[{dims}]"))
+    return found
+
+
+@pytest.fixture
+def paged_model(monkeypatch, one_chip):
+    """internlm2-1.8b at its widths with 2 layers, Pallas kernels compiled
+    for the chip (not interpreted), and shapes of its params and of the
+    benchmark's paged cache on the described chip."""
+    from repro.kernels import ops as kops
+    from repro.models import build_model
+    monkeypatch.setattr(kops, "_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config("internlm2-1.8b"), n_layers=2)
+    model = build_model(cfg, use_pallas=True, remat=False)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(lambda: model.init_cache(
+        POOL_BATCH, POOL_LEN, vector_pos=True, kv_layout="paged",
+        n_blocks=POOL_BLOCKS, block_size=BLOCK)))
+    layer_pool = math.prod(cache["k"].shape[1:]) * 2
+    assert layer_pool == 117_964_800
+    return model, params, cache, layer_pool
+
+
+def test_paged_decode_step_moves_no_layer_pool(paged_model, one_chip):
+    """The whole paged ``decode_step``, cache donated as the engine
+    donates it: no copy, dynamic-slice or dynamic-update-slice makes an
+    array of one layer's pool or more, and the decode kernel is there
+    under the name its reader matches."""
+    model, params, cache, layer_pool = paged_model
+    tokens = jax.ShapeDtypeStruct((POOL_BATCH, 1), jnp.int32,
+                                  sharding=one_chip)
+    compiled = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+        params, cache, tokens).compile()
+    assert _pool_sized_moves(compiled, layer_pool) == []
+    assert _kernel_kinds(compiled) & set(_reader_kernels(
+        "decode_attn_roofline"))
+
+
+def test_paged_chunk_step_moves_no_layer_pool(paged_model, one_chip):
+    """The same for ``prefill_chunk`` routed into the pool through a
+    block table (group 4, chunk 512), as the engine's chunk program runs
+    it."""
+    model, params, cache, layer_pool = paged_model
+    group, chunk = 4, 512
+
+    def chunk_step(params, cache, tokens, base, last, tbls, rem):
+        return model.prefill_chunk(params, cache, tokens, base,
+                                   last_pos=last, block_tbl=tbls, lens=rem)
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    rows = arg((group,))
+    compiled = jax.jit(chunk_step, donate_argnums=(1,)).lower(
+        params, cache, arg((group, chunk)), arg(()), rows,
+        arg((group, POOL_LEN // BLOCK)), rows).compile()
+    assert _pool_sized_moves(compiled, layer_pool) == []
     assert _kernel_kinds(compiled) & set(_reader_kernels(
         "prefill_attn_roofline"))
